@@ -15,6 +15,8 @@ import os
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 BENCH_COMPILED_PATH = os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "..", "BENCH_compiled.json")
 
@@ -87,11 +89,12 @@ def main() -> None:
             print(f"{name},nan,FAILED:{type(e).__name__}:{e}")
             failed.append(name)
     print(f"# total {time.time()-t0:.1f}s", file=sys.stderr)
-    if args.only and failed:
-        # single-bench invocations are CI smoke gates: their internal
-        # assertions (compile-miss bounds, bit-identity) must fail the step
+    if failed:
+        # every bench asserts its own invariants (compile-miss bounds,
+        # bit-identity): one that failed fails the run
         sys.exit(1)
 
 
 if __name__ == "__main__":
+    enable_compile_cache(os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
     main()

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.aqpeval import GuaranteedEvaluator
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models import build_model
 
@@ -50,4 +51,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     main()

@@ -17,6 +17,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import numpy as np
 
 from repro.api import Session, avg_, count_, sum_
+from repro.compile_cache import enable_compile_cache
 from repro.engine.datagen import tpch_catalog
 from repro.engine.expr import Col
 
@@ -93,4 +94,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     main()

@@ -15,6 +15,7 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.api import Session
+from repro.compile_cache import enable_compile_cache
 from repro.engine.datagen import tpch_catalog
 
 SQL = """
@@ -54,4 +55,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     main()

@@ -11,6 +11,7 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.serve import main as serve_main
 
 
@@ -24,4 +25,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     main()

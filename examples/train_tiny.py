@@ -12,6 +12,7 @@ import tempfile
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.train import main as train_main
 
 
@@ -27,4 +28,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     main()

@@ -1165,10 +1165,11 @@ class Session:
                 ans = self.db.run_fused(
                     handle.query, handle.spec, seed=handle.seed,
                     pilot_seed=self._pilot_seed_for(handle))
-            except Exception:
+            except Exception as e:
                 # fusion is an optimization, never a failure mode: the
                 # two-stage path re-runs the query from scratch and captures
                 # any genuine execution failure on the handle itself
+                self.executor.note_swallowed("fused", e)
                 ans = None
             sp.set(engaged=ans is not None,
                    fallback=None if ans is None else ans.report.fallback)

@@ -530,9 +530,10 @@ class PilotDB:
                     [prel[i][0].plan for i in idxs], pt,
                     [m[3] for m in members],
                     [{pt: m[1]} for m in members])
-            except Exception:
+            except Exception as e:
                 # stacking is an optimization, never a failure mode: these
                 # members re-run solo, bit-identical by seed derivation
+                ex.note_swallowed("batched_pilots", e)
                 solo.extend(idxs)
                 continue
             for (i, _, th, _), st in zip(members, stats):

@@ -136,18 +136,17 @@ def _slice_blocks(table: BlockTable, lo: int, hi: int, device) -> BlockTable:
     br = table.block_rows
     sl = slice(lo * br, hi * br)
 
-    def place(arr):
-        piece = arr[sl]
-        return jax.device_put(piece, device) if device is not None else piece
+    def place(arr):  # device None: the default device
+        return jax.device_put(arr, device)
 
     n_rows = min(hi * br, table.num_rows) - min(lo * br, table.num_rows)
     return BlockTable(
         name=table.name,
-        columns={c: place(v) for c, v in table.columns.items()},
+        columns={c: place(v[sl]) for c, v in table.columns.items()},
         block_rows=br,
         num_rows=max(n_rows, 0),
-        valid=place(table.valid),
-        block_id=np.repeat(np.arange(lo, hi, dtype=np.int32), br),
+        valid=place(table.valid[sl]),
+        block_id=place(np.repeat(np.arange(lo, hi, dtype=np.int32), br)),
         # origin ids are global: merged per-block statistics index the
         # monolithic block space
         num_origin_blocks=table.num_origin_blocks,

@@ -129,10 +129,23 @@ class Executor:
         # staged, batched bucket, pilot, fused) — the launch inventory the
         # fused-TAQA benchmark derives its host-sync count from.
         self.device_dispatches = 0
+        # swallowed_failures counts exceptions an optimized route (batched
+        # finals / pilots, drain-group finals, fused TAQA) caught before
+        # re-running its work another way; last_swallowed names the latest.
+        # The answers stay right, but a non-zero count means a route the
+        # device should take failed, e.g. a kernel its compiler refused.
+        self.swallowed_failures = 0
+        self.last_swallowed: Optional[str] = None
 
     def _count(self, attr: str) -> None:
         with self._counter_lock:
             setattr(self, attr, getattr(self, attr) + 1)
+
+    def note_swallowed(self, site: str, exc: BaseException) -> None:
+        """Record an exception an optimized route swallowed at ``site``."""
+        with self._counter_lock:
+            self.swallowed_failures += 1
+            self.last_swallowed = f"{site}: {type(exc).__name__}: {exc}"
 
     # -- catalog management ---------------------------------------------------
     def register_table(self, name: str, table: BlockTable) -> None:
@@ -581,11 +594,12 @@ class Executor:
                     continue
                 try:
                     self._run_bucket(plans, chunk, drawn, results)
-                except Exception:
+                except Exception as e:
                     # a batch-level failure (e.g. the batched executable
                     # failing to compile) must not sink the other buckets —
                     # nor these members, who would succeed solo: fall back
                     # to per-member dispatches, bit-identical by design
+                    self.note_swallowed("batched_finals", e)
                     for i in chunk:
                         if results[i] is None:
                             results[i] = self._execute_captured(plans[i])

@@ -520,6 +520,16 @@ class _CompiledBase:
     needed: Dict[str, Tuple[str, ...]]
     methods: Dict[str, str]
     route: str
+    # The runtime arguments of the latest launch: ``fn.lower(last_args)``
+    # re-lowers the program that ran.  Not kept for row-sampled scans,
+    # whose per-row masks are too large to hold between launches.
+    last_args: Optional[dict] = dataclasses.field(
+        default=None, init=False, repr=False)
+
+    def _launch(self, rt: dict):
+        if not rt["mask"]:
+            self.last_args = rt
+        return self.fn(rt)
 
     def _shared_args(self) -> dict:
         """Per-table inputs that do not vary across a batch: column data,
@@ -534,22 +544,25 @@ class _CompiledBase:
 
     def _runtime_args(self, runtimes: Dict[str, ScanRuntime],
                       params=()) -> dict:
+        # Host inputs stay NumPy: jit then copies them straight to the
+        # device the table's columns live on (a dist shard's own device),
+        # not through the default device first.
         rt = self._shared_args()
         for name in self.needed:
             r = runtimes.get(name)
             method = self.methods.get(name, "none")
             if method == "block":
                 rt["ids"][name] = r.ids_dev if r.ids_dev is not None \
-                    else jnp.asarray(r.ids, jnp.int32)
+                    else np.asarray(r.ids, np.int32)
                 rt["nreal"][name] = r.nreal_dev if r.nreal_dev is not None \
-                    else jnp.asarray(r.n_real, jnp.int32)
+                    else np.int32(r.n_real)
             elif method == "row":
                 rt["mask"][name] = jnp.asarray(r.keep_mask)
-        rt["params"] = jnp.asarray(np.asarray(params, np.float32))
+        rt["params"] = np.asarray(params, np.float32)
         return rt
 
     def __call__(self, runtimes: Dict[str, ScanRuntime], params=()):
-        return self.fn(self._runtime_args(runtimes, params))
+        return self._launch(self._runtime_args(runtimes, params))
 
     def scanned_bytes(self, runtimes: Dict[str, ScanRuntime]) -> int:
         """Total scan cost of one run (see :func:`scan_cost_bytes`)."""
@@ -609,7 +622,7 @@ class CompiledBatch(_CompiledBase):
                     [jnp.asarray(r[name].keep_mask) for r in runtimes_list])
         rt["params"] = jnp.asarray(
             np.asarray(params_list, np.float32).reshape(self.batch, -1))
-        return self.fn(rt)
+        return self._launch(rt)
 
 
 @dataclasses.dataclass
@@ -666,7 +679,7 @@ class CompiledFused(_CompiledBase):
             np.asarray(solve, np.float32).reshape(-1, 5))
         rt["scal"] = jnp.asarray(np.asarray(scal, np.float32))
         rt["u"] = jnp.asarray(np.asarray(u, np.float32))
-        return self.fn(rt)
+        return self._launch(rt)
 
 
 @dataclasses.dataclass
@@ -766,6 +779,12 @@ class PhysicalCompiler:
                 fused_hits=self._kind_hits["fused"],
                 fused_misses=self._kind_misses["fused"],
                 shared_hits=self.shared_hits)
+
+    def executables(self) -> list:
+        """The executables built so far; each names its ``route``."""
+        with self._lock:
+            return [v for v in self._cache.values()
+                    if not isinstance(v, Future)]
 
     # -- route policy --------------------------------------------------------
     def _use_pallas(self) -> bool:

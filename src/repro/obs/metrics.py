@@ -358,6 +358,7 @@ def register_session_collectors(registry: MetricsRegistry, session) -> None:
         out = {
             "queries_run": getattr(s.executor, "queries_run", 0),
             "pilots_run": getattr(s.executor, "pilots_run", 0),
+            "swallowed_failures": getattr(s.executor, "swallowed_failures", 0),
         }
         rt = getattr(s, "runtime", None)
         if rt is not None:

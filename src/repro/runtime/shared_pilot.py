@@ -190,11 +190,11 @@ def execute_group(session: "Session", handles: List["QueryHandle"]) -> None:
                 session.db.run_finals_batched(list(
                     pb[0].stage for pb in by_stage.values()),
                     on_answer=_on_answer)
-            except Exception:
+            except Exception as e:
                 # batching is an optimization, never a failure mode: stages
                 # left unanswered execute solo in the completion loop below
                 # (run_final), under its per-member exception capture
-                pass
+                session.executor.note_swallowed("drain_finals", e)
 
     for pend, box in zip(subgroups, boxes):
         _complete_subgroup(session, pend, box)

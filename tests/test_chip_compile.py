@@ -1,0 +1,96 @@
+"""Compile the scan kernels for a TPU v5e at TPC-H SF10 widths, without a chip.
+
+The TPU compiler is installed wherever JAX is, and compiles for a described,
+unattached chip.  It refuses what interpret mode accepts: block shapes off the
+(8, 128) tiling, scalar-prefetch tables past the 1 MiB of SMEM.  These tests
+lower each kernel through its ``ops.py`` wrapper with ``interpret=False`` and
+check that the compiled program holds the Mosaic kernel (``tpu_custom_call``).
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.block_agg import block_agg, block_agg_batched
+from repro.kernels.filtered_agg import filtered_agg, filtered_agg_batched
+
+SF10_BLOCKS = 58_594   # ceil(60M lineitem rows / 1024)
+BLOCK_ROWS = 1024      # one (8, 128) f32 tile per column per block
+N_SAMPLED = 4096       # a 7% block sample, bucketed to a power of two
+BATCH = 4
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler to describe it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _col(sharding, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct((SF10_BLOCKS * BLOCK_ROWS,), dtype,
+                                sharding=sharding)
+
+
+def _ids(sharding, *shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
+def test_block_agg_compiles_for_v5e(one_chip, dtype):
+    _compile(lambda c, v, i: block_agg(c, v, BLOCK_ROWS, i, interpret=False),
+             _col(one_chip, dtype), _col(one_chip, jnp.bool_),
+             _ids(one_chip, N_SAMPLED))
+
+
+def test_block_agg_batched_compiles_for_v5e(one_chip):
+    _compile(lambda c, v, i: block_agg_batched(c, v, BLOCK_ROWS, i,
+                                               interpret=False),
+             _col(one_chip), _col(one_chip, jnp.bool_),
+             _ids(one_chip, BATCH, N_SAMPLED))
+
+
+def _q6_args(sharding, ids, bounds_shape):
+    # l_extendedprice, l_discount, l_shipdate (int32), l_discount,
+    # l_quantity, valid — the Q6 route's operands
+    return (_col(sharding), _col(sharding), _col(sharding, jnp.int32),
+            _col(sharding), _col(sharding), _col(sharding, jnp.bool_), ids,
+            jax.ShapeDtypeStruct(bounds_shape, jnp.float32, sharding=sharding))
+
+
+def test_filtered_agg_compiles_for_v5e(one_chip):
+    _compile(lambda x, y, a, b, c, v, i, bd: filtered_agg(
+        x, y, a, b, c, v, BLOCK_ROWS, i, bd, interpret=False),
+        *_q6_args(one_chip, _ids(one_chip, N_SAMPLED), (5,)))
+
+
+def test_filtered_agg_batched_compiles_for_v5e(one_chip):
+    _compile(lambda x, y, a, b, c, v, i, bd: filtered_agg_batched(
+        x, y, a, b, c, v, BLOCK_ROWS, i, bd, interpret=False),
+        *_q6_args(one_chip, _ids(one_chip, BATCH, N_SAMPLED), (BATCH, 5)))
+
+
+def test_id_table_past_smem_splits_and_compiles(one_chip):
+    """8 lanes x 65,536 ids is 2 MiB of ids, twice the v5e's SMEM: the
+    wrapper splits it into launches that each fit, and all compile."""
+    _compile(lambda x, y, a, b, c, v, i, bd: filtered_agg_batched(
+        x, y, a, b, c, v, BLOCK_ROWS, i, bd, interpret=False),
+        *_q6_args(one_chip, _ids(one_chip, 8, 1 << 16), (8, 5)))

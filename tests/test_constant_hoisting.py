@@ -270,6 +270,27 @@ def test_pallas_batched_lanes_bitwise_match_solo(catalog, shape, route):
     assert routes == {route}
 
 
+def test_failed_batch_reruns_solo_and_is_counted(catalog, monkeypatch):
+    """A batched launch that fails re-runs its members solo (same answers),
+    and the swallowed exception is counted rather than hidden."""
+    ex = Executor(catalog)
+    plans = [L.rewrite_scans(_q6_plan(100 + 10 * i, 1500, 24),
+                             {"lineitem": L.SampleClause("block", 0.4, seed=i)})
+             for i in range(2)]
+
+    def refuse(*_args, **_kw):
+        raise RuntimeError("batched kernel refused")
+
+    monkeypatch.setattr(ex, "_run_bucket", refuse)
+    outs = ex.execute_batch(plans)
+    for plan, out in zip(plans, outs):
+        np.testing.assert_array_equal(out.values,
+                                      Executor(catalog).execute(plan).values)
+    assert ex.swallowed_failures == 1
+    assert "batched_finals: RuntimeError: batched kernel refused" == \
+        ex.last_swallowed
+
+
 def test_execute_batch_surfaces_empty_samples_per_member(catalog):
     ex = Executor(catalog)
     good = L.rewrite_scans(_q6_plan(100, 1500, 24),

@@ -4,8 +4,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.block_agg import block_agg
-from repro.kernels.filtered_agg import filtered_agg
+from repro.kernels.block_agg import block_agg, block_agg_batched
+from repro.kernels.block_agg import ops as block_agg_ops
+from repro.kernels.filtered_agg import filtered_agg, filtered_agg_batched
 from repro.kernels.flash_attn import flash_attention
 from repro.kernels.gla_chunk import gla_chunked
 
@@ -100,6 +101,36 @@ def test_filtered_agg_empty_predicate():
     out = np.asarray(filtered_agg(x, y, f1, f2, f3, valid, br, np.arange(3),
                                   (5.0, 6.0, 5.0, 6.0, -100.0)))
     np.testing.assert_allclose(out, 0.0, atol=1e-6)
+
+
+@pytest.mark.parametrize("kernel", ["block", "filtered"])
+@pytest.mark.parametrize("batched", [False, True])
+def test_id_table_split_across_launches_is_bitwise_one_launch(
+        monkeypatch, kernel, batched):
+    """An id table larger than one launch's scalar memory splits into
+    several launches; the per-block rows are those of one launch, bitwise."""
+    rng = np.random.default_rng(9)
+    br, nb = 128, 24
+    mk = lambda: jnp.asarray(rng.normal(1, 1, nb * br).astype(np.float32))
+    x, y, f1, f2, f3 = mk(), mk(), mk(), mk(), mk()
+    valid = jnp.asarray((rng.random(nb * br) < 0.8).astype(np.float32))
+    ids = rng.integers(0, nb, size=(3, 10)).astype(np.int32)
+    bounds = np.array([[-0.5, 1.2, 0.0, 2.5, 1.0 + 0.1 * b] for b in range(3)],
+                      np.float32)
+
+    def run():
+        if kernel == "block":
+            if batched:
+                return block_agg_batched(x, valid, br, ids)
+            return block_agg(x, valid, br, ids[0])
+        if batched:
+            return filtered_agg_batched(x, y, f1, f2, f3, valid, br, ids, bounds)
+        return filtered_agg(x, y, f1, f2, f3, valid, br, ids[0], bounds[0])
+
+    whole = np.asarray(run())
+    monkeypatch.setattr(block_agg_ops, "MAX_PREFETCH_IDS", 4)
+    split = np.asarray(run())
+    np.testing.assert_array_equal(split, whole)
 
 
 # -- flash attention -------------------------------------------------------------

@@ -171,6 +171,20 @@ def test_compile_cache_hits_on_repeated_plan(catalog):
     assert info2.hits == info1.hits + 1
 
 
+def test_executable_keeps_latest_launch_args_except_row_masks(catalog):
+    ex = Executor(catalog)
+    plan = _plan(SELECTIVITY_PREDS["50%"])
+    ex.execute(L.rewrite_scans(plan, {"lineitem": L.SampleClause("block", 0.3, 1)}))
+    (block,) = ex.physical.executables()
+    ids = block.last_args["ids"]["lineitem"]
+    # the kept args re-lower the program that ran
+    lowered = block.fn.lower(block.last_args)
+    assert lowered.in_avals[0][0]["ids"]["lineitem"].shape == np.shape(ids)
+    ex.execute(L.rewrite_scans(plan, {"lineitem": L.SampleClause("row", 0.3, 1)}))
+    (row,) = [c for c in ex.physical.executables() if c is not block]
+    assert row.last_args is None
+
+
 def test_plan_signature_strips_rates_seeds_and_constants():
     p1 = L.rewrite_scans(_plan(), {"lineitem": L.SampleClause("block", 0.1, 0)})
     p2 = L.rewrite_scans(_plan(), {"lineitem": L.SampleClause("block", 0.7, 42)})
