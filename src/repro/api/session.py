@@ -931,7 +931,8 @@ class Session:
                                    having=parsed.having, limit=parsed.limit,
                                    stream=stream, t_submit=t0)
         if handle._trace is not None:
-            handle._trace.record("parse", duration_s=t_parsed - t0)
+            handle._trace.record("parse", duration_s=t_parsed - t0,
+                                 t_start=t0)
         return handle
 
     def _resolve_dictionary(self, column: str, literal: str) -> int:
@@ -1035,6 +1036,7 @@ class Session:
                 handle.query_id, sql=sql, t_start=handle.t_submit)
             handle._trace.record(
                 "lower", duration_s=time.perf_counter() - t_lower0,
+                t_start=t_lower0,
                 seed=handle.seed,
                 template=_trace.sig_hash(handle.group_key),
                 signature=_trace.sig_hash(signature))
@@ -1245,10 +1247,7 @@ class Session:
                     self._emit_event("rate_solve", qid=handle.query_id,
                                      candidates=rep.candidates,
                                      fallback=rep.fallback)
-                    with _trace.span("final", batched=False) as sp:
-                        ans = self.db.run_final(stage)
-                        sp.set(scanned_bytes=ans.report.final_scanned_bytes,
-                               fallback=ans.report.fallback)
+                    ans = self.db.run_final(stage)  # its own final span
                     self._emit_event(
                         "final", qid=handle.query_id,
                         scanned_bytes=ans.report.final_scanned_bytes,

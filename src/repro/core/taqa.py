@@ -28,6 +28,7 @@ from repro.engine.executor import (EmptySampleError, Executor, PilotStats,
                                    QueryResult)
 from repro.engine.physical import ScanRuntime
 from repro.engine.sampling import draw_block_ids, pad_block_ids
+from repro.obs import trace as _trace
 from repro.stats import chi2_ppf, normal_ppf, student_t_ppf
 
 
@@ -456,8 +457,8 @@ class PilotDB:
             outcome.fallback = "no groups in pilot"
         return outcome
 
-    def run_pilots_batched(self, reqs: List[Tuple[Query, ErrorSpec, int]]
-                           ) -> List[object]:
+    def run_pilots_batched(self, reqs: List[Tuple[Query, ErrorSpec, int]],
+                           traces=None) -> List[object]:
         """Stage 1 for many independent pilot subgroups at once, stacking
         same-shape pilot scans into single device dispatches
         (``Executor.execute_pilots_batched``).
@@ -477,77 +478,143 @@ class PilotDB:
         Ineligible members, singleton shapes, and any member whose stacked
         dispatch fails take the solo loop; either way the pilot seeds are
         content-derived, so the answers are bit-identical.
+
+        ``traces`` (optional, position-aligned with ``reqs``) are the
+        leaders' query traces.  Each member's stage is a ``pilot`` span on
+        its own trace, open from its prelude to its outcome; a solo member
+        runs its scan at once, so its span holds only its own work; a
+        stacked member's ``scan`` span holds its draw and the bucket's
+        shared dispatch.
         """
         ex = self.ex
+        traces = traces or [None] * len(reqs)
         results: List[object] = [None] * len(reqs)
         prel: List[Optional[Tuple[PilotOutcome, float]]] = [None] * len(reqs)
+        # each member's open pilot span, and the scan span of its stacked
+        # draw (a member that then runs solo closes it with redrawn=True:
+        # the solo loop redraws the same sample under a scan of its own)
+        pilots = [_trace.NULL_SPAN] * len(reqs)
+        scans = [_trace.NULL_SPAN] * len(reqs)
         solo: List[int] = []
         pend: Dict[tuple, List[tuple]] = {}
-        for i, (q, spec, pseed) in enumerate(reqs):
-            try:
-                outcome, theta_p = self._pilot_prelude(q, spec)
-            except Exception as e:  # noqa: BLE001 — per-member capture
-                results[i] = e
-                continue
-            prel[i] = (outcome, theta_p)
-            if outcome.fallback is not None:
-                results[i] = outcome
-                continue
-            pt = outcome.pilot_table
-            if (not ex.use_compiled or ex.physical._use_pallas()
-                    or outcome.pair_tables
-                    or ex.staged.ladder(pt) is not None
-                    or ex.is_sharded(pt)):
-                solo.append(i)
-                continue
-            # host-resolve the member's draw, undershoot retries included —
-            # the exact seeds and x4 bumps of the solo loop
-            n_blocks = ex.table_blocks(pt)
-            need = min(spec.min_pilot_blocks, n_blocks)
-            th, drawn_th = theta_p, theta_p
-            ids = np.zeros(0, np.int64)
-            for attempt in range(3):
-                ids = draw_block_ids(n_blocks, th, pseed + 101 * attempt)
-                drawn_th = th
-                if len(ids) >= need:
-                    break
-                th = min(th * 4.0, 1.0)
-            if len(ids) == 0:
-                solo.append(i)  # solo path owns empty-draw bookkeeping
-                continue
-            phys, n_real, n_phys = pad_block_ids(ids, n_blocks)
-            runtime = ScanRuntime("block", n_real, n_phys, phys)
-            key = ex.physical.query_signature(outcome.plan, {pt: runtime})
-            pend.setdefault((pt, key), []).append((i, runtime, th, drawn_th))
 
-        for (pt, _), members in pend.items():
-            if len(members) < 2:
-                solo.extend(m[0] for m in members)
-                continue
-            idxs = [m[0] for m in members]
-            try:
-                stats = ex.execute_pilots_batched(
-                    [prel[i][0].plan for i in idxs], pt,
-                    [m[3] for m in members],
-                    [{pt: m[1]} for m in members])
-            except Exception as e:
-                # stacking is an optimization, never a failure mode: these
-                # members re-run solo, bit-identical by seed derivation
-                ex.note_swallowed("batched_pilots", e)
-                solo.extend(idxs)
-                continue
-            for (i, _, th, _), st in zip(members, stats):
-                ex._count("pilots_run")
-                results[i] = self._pilot_postlude(prel[i][0], st, th,
-                                                  st.wall_time_s)
+        def _end(i: int) -> None:
+            if pilots[i] is _trace.NULL_SPAN:
+                return
+            out = results[i]
+            if isinstance(out, PilotOutcome):
+                rep = out.report
+                pilots[i].set(table=rep.pilot_table,
+                              theta_pilot=rep.theta_pilot,
+                              n_pilot_blocks=rep.n_pilot_blocks,
+                              scanned_bytes=rep.pilot_scanned_bytes,
+                              fallback=rep.fallback)
+            else:
+                pilots[i].set(error=f"{type(out).__name__}: {out}")
+            pilots[i].close()
 
-        for i in solo:
+        def _solo(i: int) -> None:
+            scans[i].set(redrawn=True)
+            scans[i].close()
             _, spec, pseed = reqs[i]
             outcome, theta_p = prel[i]
+            token = _trace.activate(traces[i])
             try:
                 results[i] = self._pilot_scan(outcome, spec, theta_p, pseed)
             except Exception as e:  # noqa: BLE001 — per-member capture
                 results[i] = e
+            finally:
+                _trace.deactivate(token)
+            _end(i)
+
+        try:
+            for i, (q, spec, pseed) in enumerate(reqs):
+                pilots[i] = _trace.begin(traces[i], "pilot")
+                try:
+                    outcome, theta_p = self._pilot_prelude(q, spec)
+                except Exception as e:  # noqa: BLE001 — per-member capture
+                    results[i] = e
+                    _end(i)
+                    continue
+                prel[i] = (outcome, theta_p)
+                if outcome.fallback is not None:
+                    results[i] = outcome
+                    _end(i)
+                    continue
+                pt = outcome.pilot_table
+                if (not ex.use_compiled or ex.physical._use_pallas()
+                        or outcome.pair_tables
+                        or ex.staged.ladder(pt) is not None
+                        or ex.is_sharded(pt)):
+                    _solo(i)
+                    continue
+                # host-resolve the member's draw, undershoot retries
+                # included — the exact seeds and x4 bumps of the solo loop
+                scans[i] = _trace.begin(traces[i], "scan", pilot=True,
+                                        table=pt)
+                token = _trace.activate(traces[i])
+                try:
+                    with _trace.span("draw") as sp:
+                        n_blocks = ex.table_blocks(pt)
+                        need = min(spec.min_pilot_blocks, n_blocks)
+                        th, drawn_th = theta_p, theta_p
+                        ids = np.zeros(0, np.int64)
+                        for attempt in range(3):
+                            ids = draw_block_ids(n_blocks, th,
+                                                 pseed + 101 * attempt)
+                            drawn_th = th
+                            if len(ids) >= need:
+                                break
+                            th = min(th * 4.0, 1.0)
+                        if len(ids):
+                            phys, n_real, n_phys = pad_block_ids(ids,
+                                                                 n_blocks)
+                            sp.set(n_blocks=n_real, n_phys=n_phys)
+                finally:
+                    _trace.deactivate(token)
+                if len(ids) == 0:
+                    solo.append(i)  # solo path owns empty-draw bookkeeping
+                    continue
+                runtime = ScanRuntime("block", n_real, n_phys, phys)
+                key = ex.physical.query_signature(outcome.plan, {pt: runtime})
+                pend.setdefault((pt, key), []).append(
+                    (i, runtime, th, drawn_th))
+
+            for (pt, _), members in pend.items():
+                if len(members) < 2:
+                    solo.extend(m[0] for m in members)
+                    continue
+                idxs = [m[0] for m in members]
+                for i in idxs:
+                    pilots[i].set(batched=len(idxs))
+                    scans[i].set(batched=len(idxs))
+                try:
+                    stats = ex.execute_pilots_batched(
+                        [prel[i][0].plan for i in idxs], pt,
+                        [m[3] for m in members],
+                        [{pt: m[1]} for m in members],
+                        traces=[traces[i] for i in idxs])
+                except Exception as e:
+                    # stacking is an optimization, never a failure mode:
+                    # these members re-run solo, bit-identical by seed
+                    # derivation
+                    ex.note_swallowed("batched_pilots", e)
+                    solo.extend(idxs)
+                    continue
+                for (i, _, th, _), st in zip(members, stats):
+                    ex._count("pilots_run")
+                    results[i] = self._pilot_postlude(prel[i][0], st, th,
+                                                      st.wall_time_s)
+                    scans[i].set(scanned_bytes=st.scanned_bytes,
+                                 n_blocks=st.n_sampled_blocks)
+                    scans[i].close()
+                    _end(i)
+
+            for i in solo:
+                _solo(i)
+        finally:
+            for sp in scans + pilots:
+                sp.close()
         return results
 
     def run_fused(self, q: Query, spec: ErrorSpec, seed: int = 0,
@@ -739,28 +806,33 @@ class PilotDB:
 
         constraints: List[Constraint] = []
         infeasible_reason = None
-        for comp, idxs in zip(q.aggs, comp_channels):
-            e_part = propagation.split_budget(comp.kind, spec.error)
-            for ch in idxs:
-                budget = allocate(spec.confidence, n_constraints, e_part)
-                for g in present:
-                    y = pilot.block_sums[:, g, ch]
-                    # L_μ of the population total: N · (block-mean lower bound)
-                    L_mu = pilot.n_total_blocks * bsap.block_mean_lower(y, budget.delta1)
-                    if not np.isfinite(L_mu) or L_mu <= 0.0:
-                        infeasible_reason = (
-                            f"non-positive aggregate lower bound (agg={comp.name}, group={g})")
+        with _trace.span("bounds"):
+            for comp, idxs in zip(q.aggs, comp_channels):
+                e_part = propagation.split_budget(comp.kind, spec.error)
+                for ch in idxs:
+                    budget = allocate(spec.confidence, n_constraints, e_part)
+                    for g in present:
+                        y = pilot.block_sums[:, g, ch]
+                        # L_μ of the population total: N · (block-mean
+                        # lower bound)
+                        L_mu = pilot.n_total_blocks * bsap.block_mean_lower(
+                            y, budget.delta1)
+                        if not np.isfinite(L_mu) or L_mu <= 0.0:
+                            infeasible_reason = (
+                                f"non-positive aggregate lower bound "
+                                f"(agg={comp.name}, group={g})")
+                            break
+                        z = bsap.z_for(budget.p_prime)
+                        var_fn = self._make_var_fn(
+                            pilot, pilot_table, pair_tables, ch, g, theta_p,
+                            budget.delta2)
+                        constraints.append(Constraint(
+                            label=f"{comp.name}[g{g}]ch{ch}", z=z, L_mu=L_mu,
+                            error=budget.error, var_fn=var_fn))
+                    if infeasible_reason:
                         break
-                    z = bsap.z_for(budget.p_prime)
-                    var_fn = self._make_var_fn(pilot, pilot_table, pair_tables,
-                                               ch, g, theta_p, budget.delta2)
-                    constraints.append(Constraint(
-                        label=f"{comp.name}[g{g}]ch{ch}", z=z, L_mu=L_mu,
-                        error=budget.error, var_fn=var_fn))
                 if infeasible_reason:
                     break
-            if infeasible_reason:
-                break
         if infeasible_reason:
             report.plan_time_s = time.perf_counter() - t0
             stage.answer = self._exact(q, plan, comp_channels, report,
@@ -769,14 +841,17 @@ class PilotDB:
 
         # --- Stage 2: plan optimization ----------------------------------------
         sampleable = [pilot_table] + [t for t in pair_tables]
-        candidates = solve_candidates(constraints, sampleable,
-                                      max_rate=spec.max_final_rate)
+        with _trace.span("solve"):
+            candidates = solve_candidates(constraints, sampleable,
+                                          max_rate=spec.max_final_rate)
         report.candidates = len(candidates)
-        chosen = pick_plan(
-            candidates,
-            cost_fn=lambda rates: cost_mod.plan_cost(plan, self.ex.catalog, rates),
-            exact_cost=report.exact_cost,
-        )
+        with _trace.span("pick"):
+            chosen = pick_plan(
+                candidates,
+                cost_fn=lambda rates: cost_mod.plan_cost(
+                    plan, self.ex.catalog, rates),
+                exact_cost=report.exact_cost,
+            )
         report.plan_time_s = time.perf_counter() - t0
         if chosen is None:
             stage.answer = self._exact(q, plan, comp_channels, report,
@@ -791,24 +866,33 @@ class PilotDB:
         return stage
 
     def run_final(self, stage: FinalStage) -> ApproxAnswer:
-        """The execution half of stage 2 for one query, solo."""
+        """The execution half of stage 2 for one query, solo, under a
+        ``final`` span (none when planning already answered it)."""
         if stage.answer is not None:
             return stage.answer
-        t0 = time.perf_counter()
-        try:
-            res = self.ex.execute(stage.final_plan)
-        except EmptySampleError as e:
-            # The planner's rate drew zero blocks — no unbiased upscale
-            # exists, so PilotDB's "never return an unguaranteed estimate"
-            # contract forces the exact path (explicitly, not via a
-            # fabricated scale).
-            stage.report.final_time_s = time.perf_counter() - t0
-            return self._exact(stage.q, stage.plan, stage.comp_channels,
-                               stage.report, f"final sample empty ({e.table})")
-        return self._finish_result(stage, res, time.perf_counter() - t0)
+        with _trace.span("final", batched=False) as sp:
+            t0 = time.perf_counter()
+            try:
+                res = self.ex.execute(stage.final_plan)
+            except EmptySampleError as e:
+                # The planner's rate drew zero blocks — no unbiased upscale
+                # exists, so PilotDB's "never return an unguaranteed
+                # estimate" contract forces the exact path (explicitly, not
+                # via a fabricated scale).
+                stage.report.final_time_s = time.perf_counter() - t0
+                ans = self._exact(stage.q, stage.plan, stage.comp_channels,
+                                  stage.report,
+                                  f"final sample empty ({e.table})")
+            else:
+                ans = self._finish_result(stage, res,
+                                          time.perf_counter() - t0)
+                sp.set(n_blocks=_blocks_read(res))
+            sp.set(scanned_bytes=ans.report.final_scanned_bytes,
+                   fallback=ans.report.fallback)
+        return ans
 
     def run_finals_batched(self, stages: List[FinalStage],
-                           on_answer=None) -> None:
+                           on_answer=None, traces=None) -> None:
         """Execute many prepared finals, one stacked device dispatch per
         same-signature bucket (``Executor.execute_batch``), filling each
         stage's ``answer``.
@@ -827,29 +911,50 @@ class PilotDB:
         exceptions; one that escapes is swallowed here (batching is an
         optimization, never a failure mode) and the member completes on the
         caller's serial completion path instead.
+
+        ``traces`` (optional, position-aligned with ``stages``) are the
+        members' query traces: each member's ``final`` span (``batched=
+        True``) is open from the batch's start until its answer lands.
         """
-        pend = [s for s in stages if s.answer is None]
+        traces = traces or [None] * len(stages)
+        pend = [(s, tr) for s, tr in zip(stages, traces) if s.answer is None]
         if not pend:
             return
+        finals = [_trace.begin(tr, "final", batched=True) for _, tr in pend]
         t0 = time.perf_counter()
 
         def _land(i: int, res) -> None:
-            stage = pend[i]
+            stage, tr = pend[i]
             elapsed = time.perf_counter() - t0
-            if isinstance(res, EmptySampleError):
-                stage.report.final_time_s = elapsed
-                stage.answer = self._exact(
-                    stage.q, stage.plan, stage.comp_channels, stage.report,
-                    f"final sample empty ({res.table})")
-            else:
-                stage.answer = self._finish_result(stage, res, elapsed)
+            token = _trace.activate(tr)
+            try:
+                if isinstance(res, EmptySampleError):
+                    stage.report.final_time_s = elapsed
+                    stage.answer = self._exact(
+                        stage.q, stage.plan, stage.comp_channels,
+                        stage.report, f"final sample empty ({res.table})")
+                else:
+                    stage.answer = self._finish_result(stage, res, elapsed)
+                    finals[i].set(n_blocks=_blocks_read(res))
+            finally:
+                _trace.deactivate(token)
+            rep = stage.answer.report
+            finals[i].set(scanned_bytes=rep.final_scanned_bytes,
+                          fallback=rep.fallback)
+            finals[i].close()
             if on_answer is not None:
                 try:
                     on_answer(stage)
                 except Exception:
                     pass  # the caller's completion loop still owns delivery
 
-        self.ex.execute_batch([s.final_plan for s in pend], on_result=_land)
+        try:
+            self.ex.execute_batch([s.final_plan for s, _ in pend],
+                                  on_result=_land,
+                                  traces=[tr for _, tr in pend])
+        finally:
+            for sp in finals:
+                sp.close()
 
     def _finish_result(self, stage: FinalStage, res,
                        elapsed_s: float) -> ApproxAnswer:
@@ -894,6 +999,13 @@ class PilotDB:
         plan, comp_channels = self._engine_plan(q)
         report = TaqaReport()
         return self._exact(q, plan, comp_channels, report, "requested exact")
+
+
+def _blocks_read(res: QueryResult) -> int:
+    """Blocks a final scan read, over its tables: the realized sample of a
+    block-sampled scan, every block of an unsampled or row-sampled one."""
+    return sum(i.n_total_blocks if i.method == "row" else i.n_sampled_blocks
+               for i in res.sample_infos.values())
 
 
 def _combine(q: Query, comp_channels, channel_values: np.ndarray) -> np.ndarray:

@@ -229,25 +229,29 @@ class DistExecutor(Executor):
         return self._execute_dist(plan, route[0], route[1], *snap)
 
     def execute_batch(self, plans: List[L.Aggregate],
-                      on_result=None) -> List[object]:
+                      on_result=None, traces=None) -> List[object]:
         """Dist-routed members run as per-shard dispatches (bit-identical
         to their solo execution by construction); the rest batch as usual.
-        ``on_result`` keeps the base contract: dist members announce per
-        member, the rest via the forwarded (index-remapped) callback."""
+        ``on_result`` and ``traces`` keep the base contract: dist members
+        announce per member, the rest via the forwarded (index-remapped)
+        callback."""
         dist_idx = {i for i, p in enumerate(plans)
                     if self._dist_route(p) is not None}
         if not dist_idx:
-            return super().execute_batch(plans, on_result=on_result)
+            return super().execute_batch(plans, on_result=on_result,
+                                         traces=traces)
+        traces = traces or [None] * len(plans)
         results: List[object] = [None] * len(plans)
         rest = [i for i in range(len(plans)) if i not in dist_idx]
         if rest:
             remap = (None if on_result is None
                      else (lambda j, r: on_result(rest[j], r)))
             for i, r in zip(rest, super().execute_batch(
-                    [plans[i] for i in rest], on_result=remap)):
+                    [plans[i] for i in rest], on_result=remap,
+                    traces=[traces[i] for i in rest])):
                 results[i] = r
         for i in sorted(dist_idx):
-            results[i] = self._execute_captured(plans[i])
+            results[i] = self._execute_captured(plans[i], traces[i])
             if on_result is not None:
                 try:
                     on_result(i, results[i])

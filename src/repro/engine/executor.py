@@ -100,6 +100,14 @@ class PilotStats:
     wall_time_s: float
 
 
+def _draw_attrs(runtimes: Dict[str, ScanRuntime]) -> Dict[str, int]:
+    """A ``draw`` span's attributes: the block-sampled scans' real and
+    padded block counts."""
+    block = [r for r in runtimes.values() if r.method == "block"]
+    return {"n_blocks": sum(r.n_real for r in block),
+            "n_phys": sum(r.n_phys for r in block)}
+
+
 class Executor:
     def __init__(self, catalog: Dict[str, BlockTable], *,
                  use_compiled: bool = True, kernel_mode: str = "auto",
@@ -412,27 +420,27 @@ class Executor:
         """
         t0 = time.perf_counter()
         origin = self.catalog[table]
-        sub = prepare_mono_subdraw(lad, rung, sample.rate)
-        self.staged.note_hit()
         _trace.annotate(staged=True, staged_table=table,
                         staged_rate=sample.rate, staged_rung=rung.rate)
-        if sub.n_real == 0:
-            # a fresh draw under the pinned seed would be empty too
-            raise EmptySampleError(table, "block", sample.rate)
-        runtimes, infos = self._scan_runtimes(plan, exclude=table)
-        self._check_empty(infos)
-        runtimes[table] = ScanRuntime("block", sub.n_real, sub.n_phys,
-                                      sub.phys, ids_dev=sub.phys_dev,
-                                      nreal_dev=sub.nreal_dev)
-        infos[table] = SampleInfo(
-            "block", sample.rate, lad.seed, sub.n_real, lad.num_blocks,
-            sub.sub_ids,
-            scanned_bytes=scan_cost_bytes(origin, "block", sub.n_real))
-        compiled = rung.compiler.compile_query(plan, runtimes)
-        self._count("device_dispatches")
-        sums_d, counts_d = compiled(runtimes, plan_constants(plan))
-        sums = np.asarray(sums_d, dtype=np.float64)
-        counts = np.asarray(counts_d, dtype=np.float64)
+        with _trace.span("draw") as sp:
+            sub = prepare_mono_subdraw(lad, rung, sample.rate)
+            self.staged.note_hit()
+            if sub.n_real == 0:
+                # a fresh draw under the pinned seed would be empty too
+                raise EmptySampleError(table, "block", sample.rate)
+            runtimes, infos = self._scan_runtimes(plan, exclude=table)
+            self._check_empty(infos)
+            runtimes[table] = ScanRuntime("block", sub.n_real, sub.n_phys,
+                                          sub.phys, ids_dev=sub.phys_dev,
+                                          nreal_dev=sub.nreal_dev)
+            infos[table] = SampleInfo(
+                "block", sample.rate, lad.seed, sub.n_real, lad.num_blocks,
+                sub.sub_ids,
+                scanned_bytes=scan_cost_bytes(origin, "block", sub.n_real))
+            if sp is not _trace.NULL_SPAN:
+                sp.set(**_draw_attrs(runtimes))
+        sums, counts, compiled = self._dispatch(rung.compiler, plan,
+                                                runtimes)
         values = self._compose_values(plan, sums, counts, self._upscale(infos))
         return QueryResult(
             agg_names=[a.name for a in plan.aggs],
@@ -450,17 +458,10 @@ class Executor:
         if route is not None:
             return self._execute_staged(plan, *route)
         t0 = time.perf_counter()
-        runtimes, infos = self._scan_runtimes(plan)
+        runtimes, infos = self._draw(plan)
         self._check_empty(infos)
-        compiled = self.physical.compile_query(plan, runtimes)
-        # Predicate/expression constants ride as a runtime operand: the
-        # compiled executable is shared across every constant variant.
-        self._count("device_dispatches")
-        sums_d, counts_d = compiled(runtimes, plan_constants(plan))
-        # Single device→host boundary: the whole scan→aggregate pipeline ran
-        # as one executable.
-        sums = np.asarray(sums_d, dtype=np.float64)
-        counts = np.asarray(counts_d, dtype=np.float64)
+        sums, counts, compiled = self._dispatch(self.physical, plan,
+                                                runtimes)
         values = self._compose_values(plan, sums, counts, self._upscale(infos))
         return QueryResult(
             agg_names=[a.name for a in plan.aggs],
@@ -472,6 +473,32 @@ class Executor:
             sample_infos=infos,
             wall_time_s=time.perf_counter() - t0,
         )
+
+    def _draw(self, plan: L.Aggregate):
+        """:meth:`_scan_runtimes` under a ``draw`` span."""
+        with _trace.span("draw") as sp:
+            runtimes, infos = self._scan_runtimes(plan)
+            if sp is not _trace.NULL_SPAN:
+                sp.set(**_draw_attrs(runtimes))
+        return runtimes, infos
+
+    def _dispatch(self, compiler, plan: L.Aggregate,
+                  runtimes: Dict[str, ScanRuntime]):
+        """One solo scan executable: look it up, call it, pull its sums;
+        returns (sums, counts, compiled)."""
+        with _trace.span("dispatch"):
+            compiled = compiler.compile_query(plan, runtimes)
+            # Predicate/expression constants ride as a runtime operand: the
+            # compiled executable is shared across every constant variant.
+            self._count("device_dispatches")
+            sums_d, counts_d = compiled(runtimes, plan_constants(plan))
+        # Single device→host boundary: the whole scan→aggregate pipeline
+        # ran as one executable.
+        with _trace.span("device_wait", bytes=sums_d.nbytes
+                         + counts_d.nbytes):
+            sums = np.asarray(sums_d, dtype=np.float64)
+            counts = np.asarray(counts_d, dtype=np.float64)
+        return sums, counts, compiled
 
     def _execute_eager(self, plan: L.Aggregate) -> QueryResult:
         t0 = time.perf_counter()
@@ -503,16 +530,21 @@ class Executor:
         )
 
     # -- batched execution (drain-group finals) ------------------------------
-    def _execute_captured(self, plan: L.Aggregate):
-        """execute(), with EmptySampleError returned instead of raised (the
-        per-member contract of :meth:`execute_batch`)."""
+    def _execute_captured(self, plan: L.Aggregate,
+                          trace: Optional[_trace.QueryTrace] = None):
+        """execute() with ``trace`` active, EmptySampleError returned
+        instead of raised (the per-member contract of
+        :meth:`execute_batch`)."""
+        token = _trace.activate(trace)
         try:
             return self.execute(plan)
         except EmptySampleError as e:
             return e
+        finally:
+            _trace.deactivate(token)
 
     def execute_batch(self, plans: List[L.Aggregate],
-                      on_result=None) -> List[object]:
+                      on_result=None, traces=None) -> List[object]:
         """Execute several plans, batching same-signature members into ONE
         device dispatch each (see ``physical.compile_batched_query``).
 
@@ -547,10 +579,22 @@ class Executor:
         with ZERO wasted lanes — padding would recompute up to 2x of the
         device work, which at CPU scale costs more than the dispatches it
         saves.
+
+        ``traces`` (optional, position-aligned, None entries allowed) are
+        the members' query traces: each member's draw is a ``draw`` span
+        under a ``scan`` span of its own, and a stacked dispatch is a
+        :func:`repro.obs.trace.shared_span` over its members.
         """
         results: List[object] = [None] * len(plans)
+        traces = traces or [None] * len(plans)
+        # each member's open scan span, from its draw to its landing; a
+        # member that then runs solo closes it (redrawn=True: the solo
+        # path redraws the same content-derived sample under a scan of
+        # its own)
+        scans = [_trace.NULL_SPAN] * len(plans)
 
         def _land(i: int, res: object) -> None:
+            scans[i].close()
             results[i] = res
             if on_result is not None:
                 try:
@@ -558,72 +602,95 @@ class Executor:
                 except Exception:
                     pass
 
+        def _solo(i: int) -> object:
+            scans[i].set(redrawn=True)
+            scans[i].close()
+            return self._execute_captured(plans[i], traces[i])
+
         if not self.use_compiled or len(plans) < 2:
-            for i, p in enumerate(plans):
-                _land(i, self._execute_captured(p))
+            for i in range(len(plans)):
+                _land(i, _solo(i))
             return results
 
         drawn: Dict[int, tuple] = {}
         buckets: Dict[tuple, List[int]] = {}
-        for i, plan in enumerate(plans):
-            if self._staged_route(plan) is not None:
-                # staged members run solo against their rung arrays — their
-                # dispatch is already the cheap path, and batching them
-                # would redraw fresh (the ladder seed keeps that bitwise
-                # identical, but it forfeits the staged win)
-                _land(i, self._execute_captured(plan))
-                continue
-            runtimes, infos = self._scan_runtimes(plan)
-            try:
-                self._check_empty(infos)
-            except EmptySampleError as e:
-                self._count("queries_run")
-                _land(i, e)
-                continue
-            drawn[i] = (runtimes, infos)
-            key = self.physical.query_signature(plan, runtimes)
-            buckets.setdefault(key, []).append(i)
-
-        for idxs in buckets.values():
-            while idxs:
-                take = min(1 << (len(idxs).bit_length() - 1), len(idxs))
-                chunk, idxs = idxs[:take], idxs[take:]
-                if len(chunk) == 1:
-                    # the solo path redraws the same content-derived sample
-                    _land(chunk[0], self._execute_captured(plans[chunk[0]]))
+        try:
+            for i, plan in enumerate(plans):
+                if self._staged_route(plan) is not None:
+                    # staged members run solo against their rung arrays —
+                    # their dispatch is already the cheap path, and batching
+                    # them would redraw fresh (the ladder seed keeps that
+                    # bitwise identical, but it forfeits the staged win)
+                    _land(i, _solo(i))
                     continue
+                scans[i] = _trace.begin(traces[i], "scan")
+                token = _trace.activate(traces[i])
                 try:
-                    self._run_bucket(plans, chunk, drawn, results)
-                except Exception as e:
-                    # a batch-level failure (e.g. the batched executable
-                    # failing to compile) must not sink the other buckets —
-                    # nor these members, who would succeed solo: fall back
-                    # to per-member dispatches, bit-identical by design
-                    self.note_swallowed("batched_finals", e)
+                    runtimes, infos = self._draw(plan)
+                finally:
+                    _trace.deactivate(token)
+                try:
+                    self._check_empty(infos)
+                except EmptySampleError as e:
+                    self._count("queries_run")
+                    _land(i, e)
+                    continue
+                drawn[i] = (runtimes, infos)
+                key = self.physical.query_signature(plan, runtimes)
+                buckets.setdefault(key, []).append(i)
+
+            for idxs in buckets.values():
+                while idxs:
+                    take = min(1 << (len(idxs).bit_length() - 1), len(idxs))
+                    chunk, idxs = idxs[:take], idxs[take:]
+                    if len(chunk) == 1:
+                        # the solo path redraws the same content-derived
+                        # sample
+                        _land(chunk[0], _solo(chunk[0]))
+                        continue
                     for i in chunk:
-                        if results[i] is None:
-                            results[i] = self._execute_captured(plans[i])
-                # per-bucket landing: the whole chunk materializes in one
-                # device dispatch, so its members are announced together
-                if on_result is not None:
+                        scans[i].set(batched=len(chunk))
+                    try:
+                        self._run_bucket(plans, chunk, drawn, results,
+                                         [traces[i] for i in chunk])
+                        for i in chunk:
+                            scans[i].set(
+                                scanned_bytes=results[i].scanned_bytes)
+                    except Exception as e:
+                        # a batch-level failure (e.g. the batched executable
+                        # failing to compile) must not sink the other
+                        # buckets — nor these members, who would succeed
+                        # solo: fall back to per-member dispatches,
+                        # bit-identical by design
+                        self.note_swallowed("batched_finals", e)
+                        for i in chunk:
+                            if results[i] is None:
+                                results[i] = _solo(i)
+                    # per-bucket landing: the whole chunk materializes in
+                    # one device dispatch, so its members are announced
+                    # together
                     for i in chunk:
-                        try:
-                            on_result(i, results[i])
-                        except Exception:
-                            pass
+                        _land(i, results[i])
+        finally:
+            for sp in scans:
+                sp.close()
         return results
 
-    def _run_bucket(self, plans, idxs, drawn, results) -> None:
+    def _run_bucket(self, plans, idxs, drawn, results, traces) -> None:
         t0 = time.perf_counter()
-        compiled = self.physical.compile_batched_query(
-            plans[idxs[0]], drawn[idxs[0]][0], len(idxs))
-        self._count("device_dispatches")
-        sums_b, counts_b = compiled.call_batch(
-            [drawn[i][0] for i in idxs],
-            [plan_constants(plans[i]) for i in idxs])
+        b = len(idxs)
+        with _trace.shared_span(traces, "dispatch", batched=b):
+            compiled = self.physical.compile_batched_query(
+                plans[idxs[0]], drawn[idxs[0]][0], b)
+            self._count("device_dispatches")
+            sums_d, counts_d = compiled.call_batch(
+                [drawn[i][0] for i in idxs],
+                [plan_constants(plans[i]) for i in idxs])
         # one device→host boundary for the whole bucket
-        sums_b = np.asarray(sums_b, dtype=np.float64)
-        counts_b = np.asarray(counts_b, dtype=np.float64)
+        with _trace.shared_span(traces, "device_wait", batched=b,
+                                bytes=sums_d.nbytes + counts_d.nbytes):
+            sums_b = np.asarray(sums_d, dtype=np.float64)
+            counts_b = np.asarray(counts_d, dtype=np.float64)
         wall = time.perf_counter() - t0
         for k, i in enumerate(idxs):
             self._count("queries_run")
@@ -691,16 +758,28 @@ class Executor:
                 and not self.physical._use_pallas()):
             rung = lad.rung_for(theta_p)
         if rung is not None:
-            sub = prepare_mono_subdraw(lad, rung, theta_p)
-            self.staged.note_hit()
             _trace.annotate(staged=True, staged_table=pilot_table,
                             staged_rate=theta_p, staged_rung=rung.rate)
-            ids, n_real = sub.sub_ids, sub.n_real
-        else:
-            if lad is not None:
-                self.staged.note_miss()
-            ids = draw_block_ids(table.num_blocks, theta_p, seed)
-            n_real = int(len(ids))
+        with _trace.span("draw") as sp:
+            if rung is not None:
+                sub = prepare_mono_subdraw(lad, rung, theta_p)
+                self.staged.note_hit()
+                n_real = sub.n_real
+                # positions within the rung, padded to the FRESH physical
+                # block count — identical graph shapes and masking, smaller
+                # gather
+                runtime = ScanRuntime("block", sub.n_real, sub.n_phys,
+                                      sub.phys, ids_dev=sub.phys_dev,
+                                      nreal_dev=sub.nreal_dev)
+                compiler = rung.compiler
+            else:
+                if lad is not None:
+                    self.staged.note_miss()
+                ids = draw_block_ids(table.num_blocks, theta_p, seed)
+                phys, n_real, n_phys = pad_block_ids(ids, table.num_blocks)
+                runtime = ScanRuntime("block", n_real, n_phys, phys)
+                compiler = self.physical
+            sp.set(n_blocks=n_real, n_phys=runtime.n_phys)
         names = [a.name for a in plan.aggs] + ["__rows"]
 
         if n_real == 0:
@@ -715,32 +794,28 @@ class Executor:
                 pair_sums={}, right_total_blocks={}, scanned_bytes=scanned,
                 wall_time_s=time.perf_counter() - t0)
 
-        if rung is not None:
-            # positions within the rung, padded to the FRESH physical block
-            # count — identical graph shapes and masking, smaller gather
-            runtime = ScanRuntime("block", sub.n_real, sub.n_phys, sub.phys,
-                                  ids_dev=sub.phys_dev,
-                                  nreal_dev=sub.nreal_dev)
-            compiler = rung.compiler
-        else:
-            phys, n_real, n_phys = pad_block_ids(ids, table.num_blocks)
-            runtime = ScanRuntime("block", n_real, n_phys, phys)
-            compiler = self.physical
         pair_table = pair_tables[0] if pair_tables else None
-        compiled = compiler.compile_pilot(plan, pilot_table, runtime,
-                                          pair_table)
-        # One executable from sampled scan to per-block statistics — zero
-        # host syncs in between; the conversions below are the boundary.
-        self._count("device_dispatches")
-        bs_d, present_d, pair_d = compiled({pilot_table: runtime},
-                                           plan_constants(plan))
-        block_sums = np.asarray(bs_d, dtype=np.float64)[:n_real]
-        present = np.asarray(present_d, dtype=bool)
+        with _trace.span("dispatch"):
+            compiled = compiler.compile_pilot(plan, pilot_table, runtime,
+                                              pair_table)
+            # One executable from sampled scan to per-block statistics —
+            # zero host syncs in between; the conversions below are the
+            # boundary.
+            self._count("device_dispatches")
+            bs_d, present_d, pair_d = compiled({pilot_table: runtime},
+                                               plan_constants(plan))
         pair_sums: Dict[str, np.ndarray] = {}
         right_total: Dict[str, int] = {}
-        if pair_d is not None:
-            pair_sums[pair_table] = np.asarray(pair_d, dtype=np.float64)[:n_real]
-            right_total[pair_table] = self.catalog[pair_table].num_blocks
+        with _trace.span("device_wait") as sp:
+            block_sums = np.asarray(bs_d, dtype=np.float64)[:n_real]
+            present = np.asarray(present_d, dtype=bool)
+            nbytes = bs_d.nbytes + present_d.nbytes
+            if pair_d is not None:
+                pair_sums[pair_table] = np.asarray(
+                    pair_d, dtype=np.float64)[:n_real]
+                right_total[pair_table] = self.catalog[pair_table].num_blocks
+                nbytes += pair_d.nbytes
+            sp.set(bytes=nbytes)
         return PilotStats(
             table=pilot_table,
             theta_p=theta_p,
@@ -812,6 +887,7 @@ class Executor:
         pilot_table: str,
         thetas: List[float],
         runtimes_list: List[Dict[str, ScanRuntime]],
+        traces=None,
     ) -> List[PilotStats]:
         """One stacked device dispatch for B same-signature pilot scans.
 
@@ -822,21 +898,26 @@ class Executor:
         pilot body under ``lax.map`` and is bit-identical to member k's solo
         ``execute_pilot``.  Pair-table, Pallas-route, staged-ladder and
         sharded pilots never reach here (the caller gates them to solo).
+
+        ``traces`` (optional, position-aligned) are the members' query
+        traces: the dispatch and the device wait are
+        :func:`repro.obs.trace.shared_span` spans over them.
         """
         batch = len(plans)
-        compiled = self.physical.compile_batched_pilot(
-            plans[0], pilot_table, runtimes_list[0][pilot_table], batch)
+        traces = traces or [None] * batch
         names_l = [[a.name for a in p.aggs] + ["__rows"] for p in plans]
-        t0 = time.perf_counter()
-        with _trace.span("scan", pilot=True, table=pilot_table,
-                         batched=batch) as sp:
+        with _trace.shared_span(traces, "dispatch", batched=batch):
+            compiled = self.physical.compile_batched_pilot(
+                plans[0], pilot_table, runtimes_list[0][pilot_table], batch)
+            t0 = time.perf_counter()
             self._count("device_dispatches")
             bs_d, present_d = compiled.call_batch(
                 runtimes_list, [plan_constants(p) for p in plans])
-            # one device→host boundary for the whole pilot group
+        # one device→host boundary for the whole pilot group
+        with _trace.shared_span(traces, "device_wait", batched=batch,
+                                bytes=bs_d.nbytes + present_d.nbytes):
             bs_b = np.asarray(bs_d, dtype=np.float64)
             present_b = np.asarray(present_d, dtype=bool)
-            sp.set(n_blocks=sum(r[pilot_table].n_real for r in runtimes_list))
         wall = time.perf_counter() - t0
         table = self.catalog[pilot_table]
         out: List[PilotStats] = []
@@ -882,21 +963,25 @@ class Executor:
         compiled = self.physical.compile_fused(plan, pilot_table, runtimes,
                                                tuple(solve_channels))
         with _trace.span("scan", fused=True, table=pilot_table) as sp:
-            self._count("device_dispatches")
-            bs_d, present_d, theta_d, flags_d, nsel_d, padded_d, sums_d, counts_d = \
-                compiled.call_fused(runtimes, plan_constants(plan),
-                                    solve, scal, u)
+            with _trace.span("dispatch"):
+                self._count("device_dispatches")
+                outs = compiled.call_fused(runtimes, plan_constants(plan),
+                                           solve, scal, u)
             # the fused program's single device→host boundary
-            out = {
-                "block_sums": np.asarray(bs_d, dtype=np.float64),
-                "present": np.asarray(present_d, dtype=bool),
-                "theta": float(theta_d),
-                "flags": int(flags_d),
-                "nsel": int(nsel_d),
-                "padded": np.asarray(padded_d),
-                "sums": np.asarray(sums_d, dtype=np.float64),
-                "counts": np.asarray(counts_d, dtype=np.float64),
-            }
+            with _trace.span("device_wait",
+                             bytes=sum(o.nbytes for o in outs)):
+                bs_d, present_d, theta_d, flags_d, nsel_d, padded_d, \
+                    sums_d, counts_d = outs
+                out = {
+                    "block_sums": np.asarray(bs_d, dtype=np.float64),
+                    "present": np.asarray(present_d, dtype=bool),
+                    "theta": float(theta_d),
+                    "flags": int(flags_d),
+                    "nsel": int(nsel_d),
+                    "padded": np.asarray(padded_d),
+                    "sums": np.asarray(sums_d, dtype=np.float64),
+                    "counts": np.asarray(counts_d, dtype=np.float64),
+                }
             sp.set(n_blocks=runtimes[pilot_table].n_real,
                    theta_final=out["theta"], fused_flags=out["flags"])
         return out, compiled

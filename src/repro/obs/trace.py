@@ -4,10 +4,14 @@ A :class:`QueryTrace` records the full life of a query as nested timed
 spans — parse → lower → schedule → pilot (shared/solo, staged-rung,
 shard-fanout tags) → rate solve (§4) → compile (hit/miss + signature) →
 final dispatch (batched/solo/staged/per-shard) → deliver — with wall times,
-``scanned_bytes`` and fallback reasons as span attributes.  Exportable as a
-JSON span tree (:meth:`QueryTrace.to_dict`) or Chrome trace-event format
-(:meth:`QueryTrace.to_chrome`, load in ``chrome://tracing`` / Perfetto) via
-``handle.trace()`` / ``handle.trace("chrome")``.
+``scanned_bytes`` and fallback reasons as span attributes.  Each device
+program a stage runs is a ``scan`` span whose children split its host
+side: ``draw`` (sample ids drawn and padded), ``dispatch`` (executable
+lookup and the jitted call) and ``device_wait`` (the device→host pulls).
+Exportable as a JSON span tree (:meth:`QueryTrace.to_dict`) or Chrome
+trace-event format (:meth:`QueryTrace.to_chrome`, load in
+``chrome://tracing`` / Perfetto) via ``handle.trace()`` /
+``handle.trace("chrome")``.
 
 Zero-overhead contract.  Tracing is opt-in (``SessionConfig.tracing``,
 default False): an untraced handle carries no trace object, nothing is
@@ -27,7 +31,21 @@ enclosing span attaches to the root — so concurrent stages never interleave
 into a bogus parent chain.  The *active* trace travels via a context var:
 layers below the session (executor, physical compiler, staged catalog,
 dist executor) call the module-level :func:`span` / :func:`annotate`
-helpers and need no handle plumbing.
+helpers and need no handle plumbing.  Work one thread does for several
+queries in turn (a drain group's stacked dispatches) gets the members'
+traces passed explicitly: :func:`begin` holds a member's span open across
+that work, and :func:`shared_span` puts one dispatch that serves several
+members on the first traced member's tree, ``owner=True``, with a
+retroactive copy (``owner=False``) on each of the others.
+
+Profiler clock.  A live span (opened by :meth:`QueryTrace.span`) also
+enters ``jax.profiler.TraceAnnotation("pilotdb." + name)`` on its thread
+for its life, so under ``jax.profiler`` the program's stages appear in the
+device trace's own clock, beside the device ops; a live span also records
+``cpu_ms``, the thread's CPU time from open to close, which set beside the
+wall time separates a stage's own work from waiting for the GIL or a lock.
+Retroactive :meth:`QueryTrace.record` spans and the cross-thread
+``schedule`` span get neither.
 
 Closure contract.  ``QueryTrace.finish`` (called by the handle's
 ``_mark_done`` / ``_mark_failed``) closes every open span and the root —
@@ -45,6 +63,7 @@ import time
 from typing import Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 _ACTIVE: "contextvars.ContextVar[Optional[QueryTrace]]" = \
     contextvars.ContextVar("pilotdb_active_trace", default=None)
@@ -73,12 +92,14 @@ def sig_hash(obj) -> str:
 class Span:
     """One timed, attributed node of the span tree."""
 
-    __slots__ = ("name", "t0", "t1", "attrs", "children", "status", "tid")
+    __slots__ = ("name", "t0", "t1", "cpu0", "attrs", "children", "status",
+                 "tid")
 
     def __init__(self, name: str, t0: Optional[float] = None):
         self.name = name
         self.t0 = time.perf_counter() if t0 is None else t0
         self.t1: Optional[float] = None
+        self.cpu0: Optional[float] = None  # thread CPU clock of a live span
         self.attrs: Dict[str, object] = {}
         self.children: List["Span"] = []
         self.status = "ok"
@@ -122,20 +143,30 @@ class _NullSpan:
     def set(self, **attrs):
         return self
 
+    def close(self) -> None:
+        pass
+
 
 NULL_SPAN = _NullSpan()
 
 
 class _SpanCtx:
-    """Context manager pairing a span with its trace's per-thread stack."""
+    """Context manager pairing a live span with its trace's per-thread
+    stack and its profiler annotation.  Entered and closed on the thread
+    that opened it, by a ``with`` block or by :func:`begin` and
+    :meth:`close`."""
 
-    __slots__ = ("_trace", "_span")
+    __slots__ = ("_trace", "_span", "_ann")
 
     def __init__(self, trace: "QueryTrace", span: Span):
         self._trace = trace
         self._span = span
+        self._ann: Optional[TraceAnnotation] = None
 
     def __enter__(self) -> Span:
+        self._ann = TraceAnnotation("pilotdb." + self._span.name)
+        self._ann.__enter__()
+        self._span.cpu0 = time.thread_time()
         return self._span
 
     def __exit__(self, exc_type, exc, tb):
@@ -143,8 +174,19 @@ class _SpanCtx:
             self._span.status = "error"
             self._span.attrs.setdefault(
                 "error", f"{exc_type.__name__}: {exc}")
-        self._trace._close(self._span)
+        self.close()
         return False
+
+    def set(self, **attrs) -> Span:
+        return self._span.set(**attrs)
+
+    def close(self) -> None:
+        """Close the span (idempotent)."""
+        if self._ann is None:
+            return
+        self._trace._close(self._span)
+        self._ann.__exit__(None, None, None)
+        self._ann = None
 
 
 class QueryTrace:
@@ -191,20 +233,26 @@ class QueryTrace:
         with self._lock:
             if sp.t1 is None:  # finish() may have force-closed it already
                 sp.t1 = time.perf_counter()
+                sp.attrs["cpu_ms"] = (time.thread_time() - sp.cpu0) * 1e3
             stack = self._stacks.get(sp.tid, [])
             if sp in stack:  # pop through sp (tolerates leaked children)
                 del stack[stack.index(sp):]
 
-    def record(self, name: str, duration_s: float = 0.0, **attrs) -> Span:
-        """Append an already-elapsed span ending now (used where the work
-        ran elsewhere — e.g. a member's view of a shared pilot stage, or a
-        final that landed inside a batched dispatch)."""
+    def record(self, name: str, duration_s: float = 0.0,
+               t_start: Optional[float] = None, **attrs) -> Span:
+        """Append an already-elapsed span under the calling thread's
+        innermost open span: it starts at ``t_start`` (a ``perf_counter``
+        reading) and lasts ``duration_s``, or ends now when no start is
+        given.  Used where the work ran elsewhere or before the trace
+        could hold it — e.g. a member's view of a shared pilot stage or of
+        a stacked dispatch."""
         with self._lock:
             if self.finished:
                 return Span(name)
-            t1 = time.perf_counter()
-            sp = Span(name, t0=t1 - max(0.0, duration_s))
-            sp.t1 = t1
+            d = max(0.0, duration_s)
+            t0 = time.perf_counter() - d if t_start is None else t_start
+            sp = Span(name, t0=t0)
+            sp.t1 = t0 + d
             sp.attrs.update(attrs)
             self._parent(threading.get_ident()).children.append(sp)
             return sp
@@ -374,6 +422,61 @@ def span(name: str, **attrs):
     if tr is None:
         return NULL_SPAN
     return tr.span(name, **attrs)
+
+
+def begin(trace: Optional[QueryTrace], name: str, **attrs):
+    """Open a live span on ``trace`` (not necessarily the active one) and
+    return its handle, to be closed later with ``.close()`` on the same
+    thread; the no-op span when ``trace`` is None.  For work that one
+    thread does for several queries in turn, where a ``with`` block cannot
+    hold each query's span."""
+    if trace is None:
+        return NULL_SPAN
+    ctx = trace.span(name, **attrs)
+    ctx.__enter__()
+    return ctx
+
+
+class _SharedSpan:
+    """See :func:`shared_span`."""
+
+    __slots__ = ("_owner", "_rest", "_ctx", "_token")
+
+    def __init__(self, owner: QueryTrace, rest: List[QueryTrace], ctx):
+        self._owner, self._rest, self._ctx = owner, rest, ctx
+        self._token = None
+
+    def __enter__(self):
+        self._token = activate(self._owner)
+        return self._ctx.__enter__()
+
+    def __exit__(self, *exc):
+        try:
+            self._ctx.__exit__(*exc)
+        finally:
+            deactivate(self._token)
+        sp = self._ctx._span if self._ctx is not NULL_SPAN else None
+        if sp is not None:
+            attrs = {k: v for k, v in sp.attrs.items() if k != "cpu_ms"}
+            attrs["owner"] = False
+            for tr in self._rest:
+                tr.record(sp.name, duration_s=sp.t1 - sp.t0, t_start=sp.t0,
+                          **attrs)
+        return False
+
+
+def shared_span(traces: List[Optional[QueryTrace]], name: str, **attrs):
+    """A span for work that serves several queries at once (a stacked
+    dispatch): live, active and ``owner=True`` on the first traced member;
+    on closing, each other traced member gets a retroactive record of the
+    same start, end and attributes with ``owner=False``, under its own
+    innermost open span on this thread.  The no-op span when no member is
+    traced."""
+    live = [t for t in traces if t is not None]
+    if not live:
+        return NULL_SPAN
+    return _SharedSpan(live[0], live[1:],
+                       live[0].span(name, owner=True, **attrs))
 
 
 def annotate(**attrs) -> None:

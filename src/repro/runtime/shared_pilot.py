@@ -138,7 +138,8 @@ def execute_group(session: "Session", handles: List["QueryHandle"]) -> None:
         gens = [session._scan_generations(live[0].query) for live in shared]
         pre = session.db.run_pilots_batched(
             [(live[0].query, live[0].spec, session._pilot_seed_for(live[0]))
-             for live in shared])
+             for live in shared],
+            traces=[live[0]._trace for live in shared])
 
     # Stage-1 fan-out: a template group may hold MANY pilot subgroups (a
     # constant-varied herd runs one pilot per constant — selectivity shapes
@@ -187,9 +188,10 @@ def execute_group(session: "Session", handles: List["QueryHandle"]) -> None:
                 _complete_one(session, p, box)
 
             try:
-                session.db.run_finals_batched(list(
-                    pb[0].stage for pb in by_stage.values()),
-                    on_answer=_on_answer)
+                session.db.run_finals_batched(
+                    [pb[0].stage for pb in by_stage.values()],
+                    on_answer=_on_answer,
+                    traces=[pb[0].handle._trace for pb in by_stage.values()])
             except Exception as e:
                 # batching is an optimization, never a failure mode: stages
                 # left unanswered execute solo in the completion loop below
@@ -207,8 +209,9 @@ def _pilot_and_prepare(session: "Session", live: List["QueryHandle"],
 
     ``pre`` threads a pilot already executed by the group-wide batched
     dispatch (``PilotDB.run_pilots_batched``) into this subgroup: a
-    :class:`PilotOutcome` skips the pilot stage here (the leader gets a
-    retroactive summary span), a captured exception fails every member —
+    :class:`PilotOutcome` skips the pilot stage here (the leader's
+    ``pilot`` span, opened there, gets the sharing tags), a captured
+    exception fails every member —
     exactly what the solo pilot's except-branch below would have done —
     and None runs the pilot as before.  ``gen`` carries the
     table-generation snapshot taken before that batched dispatch.
@@ -228,14 +231,9 @@ def _pilot_and_prepare(session: "Session", live: List["QueryHandle"],
     if pre is not None:
         outcome = pre
         rep = outcome.report
-        if leader._trace is not None:
-            leader._trace.record(
-                "pilot", duration_s=rep.pilot_time_s, shared=shared,
-                owner=True, members=len(live), batched=True,
-                table=rep.pilot_table, theta_pilot=rep.theta_pilot,
-                n_pilot_blocks=rep.n_pilot_blocks,
-                scanned_bytes=rep.pilot_scanned_bytes,
-                fallback=rep.fallback)
+        found = leader._trace.find("pilot") if leader._trace else []
+        sp = found[-1] if found else _trace.NULL_SPAN
+        sp.set(shared=shared, owner=True, members=len(live))
     else:
         # the shared pilot executes ONCE, on the leader's trace: deep tags
         # (staged rung, shard fan-out, compile hit/miss) annotate the
@@ -266,10 +264,13 @@ def _pilot_and_prepare(session: "Session", live: List["QueryHandle"],
                         scanned_bytes=rep.pilot_scanned_bytes,
                         wall_s=round(rep.pilot_time_s, 6),
                         fallback=rep.fallback)
+    # members sharing the leader's pilot get a record where it ran
+    t_start, wall = ((sp.t0, sp.t1 - sp.t0) if isinstance(sp, _trace.Span)
+                     else (None, rep.pilot_time_s))
     for h in live[1:]:
         if h._trace is not None:
             h._trace.record(
-                "pilot", duration_s=rep.pilot_time_s, shared=True,
+                "pilot", duration_s=wall, t_start=t_start, shared=True,
                 owner=False, table=rep.pilot_table,
                 theta_pilot=rep.theta_pilot,
                 n_pilot_blocks=rep.n_pilot_blocks,
@@ -374,14 +375,11 @@ def _complete_one(session: "Session", p: _Pending, box: dict) -> None:
                     p.stage = session.db.prepare_final(h.query, h.spec,
                                                        p.outcome, seed=h.seed)
             # a stage answered before this sweep means the group's batched
-            # lax.map dispatch landed it (or a rate-solve fallback
-            # short-circuited to exact) — run_final just returns it
+            # lax.map dispatch landed it, under its own final span (or a
+            # rate-solve fallback short-circuited to exact) — run_final
+            # just returns it
             pre_answered = p.stage.answer is not None
-            with _trace.span("final") as sp:
-                ans = session.db.run_final(p.stage)
-                sp.set(batched=pre_answered and ans.report.fallback is None,
-                       scanned_bytes=ans.report.final_scanned_bytes,
-                       fallback=ans.report.fallback)
+            ans = session.db.run_final(p.stage)
             session._emit_event(
                 "final", qid=h.query_id,
                 batched=pre_answered and ans.report.fallback is None,

@@ -282,6 +282,186 @@ def test_trace_span_error_status_on_exception():
     assert not sp.open
 
 
+def test_trace_record_sits_at_its_start_and_live_spans_take_cpu_time():
+    tr = QueryTrace(2)
+    with tr.span("live") as live:
+        pass
+    rec = tr.record("past", duration_s=0.25, t_start=tr.t0 + 1.0, k=1)
+    assert (rec.t0, rec.t1) == (tr.t0 + 1.0, tr.t0 + 1.25)
+    assert rec.attrs == {"k": 1} and "cpu_ms" not in rec.attrs
+    assert 0.0 <= live.attrs["cpu_ms"] <= live.duration_s * 1e3 + 1.0
+    # a held span closes once, and a held span of an untraced member is
+    # the no-op
+    held = trace_mod.begin(tr, "held")
+    held.close()
+    held.close()
+    assert tr.open_spans() == ["query"]
+    assert trace_mod.begin(None, "x") is trace_mod.NULL_SPAN
+    assert trace_mod.shared_span([None, None], "x") is trace_mod.NULL_SPAN
+
+
+def test_shared_span_owner_live_members_retroactive():
+    a, b, c = QueryTrace(0), QueryTrace(1), QueryTrace(2)
+    holds = [trace_mod.begin(t, "scan") for t in (a, b, c)]
+    with trace_mod.shared_span([None, a, b, c], "dispatch", batched=3) as sp:
+        assert trace_mod.active() is a
+        sp.set(bytes=8)
+    assert trace_mod.active() is None
+    for h in holds:
+        h.close()
+    own, = a.find("dispatch")
+    assert own.attrs["owner"] is True and "cpu_ms" in own.attrs
+    for t in (b, c):
+        rec, = t.find("dispatch")
+        assert (rec.t0, rec.t1) == (own.t0, own.t1)
+        assert rec.attrs == {"owner": False, "batched": 3, "bytes": 8}
+        scan, = t.find("scan")
+        assert scan.children == [rec]  # under the member's held span
+
+
+# ---------------------------------------------------------------------------
+# Stage spans of a gateway drain: draw, dispatch, device wait, solve parts
+# ---------------------------------------------------------------------------
+
+Q6_SQL = ("SELECT SUM(l_extendedprice * l_discount) AS rev FROM lineitem "
+          "WHERE l_shipdate BETWEEN {d} AND {e} "
+          "AND l_discount BETWEEN 0.05 AND 0.07 AND l_quantity < 24 "
+          "ERROR 10% CONFIDENCE 95%")
+Q6_DAYS = range(100, 900, 100)  # 8 constants: 8 pilot subgroups
+
+
+@pytest.fixture(scope="module")
+def q6_catalog():
+    # big enough blocks that Q6 samples (smaller ones fall back to exact)
+    return tpch_catalog(scale_rows=2_000_000, block_rows=512, seed=0)
+
+
+def _q6_drain(catalog, tracing):
+    s = Session(catalog, seed=7, config=SessionConfig(
+        tracing=tracing, result_cache_size=0))
+    g = SqlGateway(s)
+    tickets = [g.submit("c", Q6_SQL.format(d=d, e=d + 364)) for d in Q6_DAYS]
+    out = g.run()
+    s.close()
+    return [out[t] for t in tickets]
+
+
+@pytest.fixture(scope="module")
+def q6_drains(q6_catalog):
+    """One gateway drain of 8 constant-varied Q6 queries, untraced and
+    traced: the stacked pilot and stacked final paths."""
+    return _q6_drain(q6_catalog, False), _q6_drain(q6_catalog, True)
+
+
+def _children(sp, name):
+    return [c for c in sp.children if c.name == name]
+
+
+def test_traced_gateway_q6_drain_bitwise(q6_drains):
+    plain, traced = q6_drains
+    for a, b in zip(plain, traced):
+        assert a.status == b.status == "done" and b.fallback is None
+        _assert_bitwise(b.answer, a.answer)
+    # the drain took the stacked routes the spans are for
+    pilots = [sp for h in traced for sp in h._trace.find("pilot")]
+    finals = [sp for h in traced for sp in h._trace.find("final")]
+    assert any(sp.attrs.get("batched", 0) >= 2 for sp in pilots)
+    assert all(sp.attrs["batched"] is True for sp in finals)
+    assert any(sc.attrs.get("batched", 0) >= 2
+               for sp in finals for sc in _children(sp, "scan"))
+
+
+def test_drain_trees_hold_every_scan_stage(q6_drains):
+    for h in q6_drains[1]:
+        tr = h._trace
+        assert tr.finished and tr.open_spans() == []
+        for stage in ("pilot", "final"):
+            sp, = tr.find(stage)
+            scans = [sc for sc in _children(sp, "scan")
+                     if not sc.attrs.get("redrawn")]
+            assert scans, f"{stage} holds no scan"
+            for sc in scans:
+                assert [c.name for c in sc.children] == [
+                    "draw", "dispatch", "device_wait"]
+                draw = sc.children[0]
+                assert 0 < draw.attrs["n_blocks"] <= draw.attrs["n_phys"]
+                assert sc.children[2].attrs["bytes"] > 0
+        solve, = tr.find("rate_solve")
+        assert [c.name for c in solve.children] == ["bounds", "solve", "pick"]
+
+
+def test_retroactive_spans_lie_inside_their_owners(q6_drains):
+    spans = [sp for h in q6_drains[1] for name in ("dispatch", "device_wait")
+             for sp in h._trace.find(name)]
+    owners = [sp for sp in spans if sp.attrs.get("owner") is True]
+    copies = [sp for sp in spans if sp.attrs.get("owner") is False]
+    assert owners and copies
+    for c in copies:
+        assert any(o.name == c.name and o.t0 <= c.t0 and c.t1 <= o.t1
+                   and o.attrs["batched"] == c.attrs["batched"]
+                   for o in owners)
+    # spans are where the work ran: every child inside its parent
+    for h in q6_drains[1]:
+        for sp in h._trace.find("scan"):
+            assert all(sp.t0 <= c.t0 and c.t1 <= sp.t1 for c in sp.children)
+
+
+def test_cpu_time_never_exceeds_wall_time(q6_drains):
+    seen = 0
+    for h in q6_drains[1]:
+        for name in set(h._trace.span_names()):
+            for sp in h._trace.find(name):
+                if "cpu_ms" in sp.attrs:
+                    seen += 1
+                    assert sp.attrs["cpu_ms"] <= sp.duration_s * 1e3 + 1.0
+    assert seen
+
+
+def test_final_blocks_times_block_bytes_is_final_scanned_bytes(
+        q6_catalog, q6_drains):
+    t = q6_catalog["lineitem"]
+    for h in q6_drains[1]:
+        final, = h._trace.find("final")
+        assert final.attrs["n_blocks"] * t.block_rows * t.row_bytes() == \
+            h.report.final_scanned_bytes == final.attrs["scanned_bytes"]
+
+
+def test_shared_pilot_members_recorded_inside_the_leader_pilot(catalog):
+    rt = Session(catalog, seed=11, config=TRACE_HERD)
+    handles = [rt.submit(HERD_SQL) for _ in range(3)]
+    rt.drain()
+    lead, = [sp for h in handles for sp in h._trace.find("pilot")
+             if sp.attrs["owner"]]
+    for h in handles:
+        sp, = h._trace.find("pilot")
+        assert lead.t0 <= sp.t0 and sp.t1 <= lead.t1
+    rt.close()
+
+
+def test_live_spans_appear_as_profiler_annotations(catalog, tmp_path):
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+
+    s = Session(catalog, seed=3, config=TRACE_SERIAL)
+    s.sql(HERD_SQL)  # compile outside the profiled call
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        h = s.sql(HERD_SQL.replace("< 24", "< 25"))
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    names = {e.name for p in ProfileData.from_file(path).planes
+             for ln in p.lines for e in ln.events}
+    assert {"pilotdb.pilot", "pilotdb.rate_solve", "pilotdb.final",
+            "pilotdb.draw", "pilotdb.dispatch", "pilotdb.device_wait",
+            "pilotdb.bounds", "pilotdb.solve", "pilotdb.pick"} <= names
+    # retroactive records and the cross-thread schedule span are not live
+    assert not {"pilotdb.parse", "pilotdb.lower"} & names
+    assert h.fallback is None
+
+
 # ---------------------------------------------------------------------------
 # Metrics registry
 # ---------------------------------------------------------------------------
