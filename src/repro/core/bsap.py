@@ -116,11 +116,14 @@ def single_table_var_ub(y: np.ndarray, theta_p: float, delta2: float,
         N = float(n_blocks)
     else:
         N = population_lower_bound(n_p, theta_p, delta2 / parts)
+    # the binomial bound's percentile does not depend on θ: once, not per
+    # step of the planner's bisection
+    z_b = normal_ppf(1.0 - delta2 / parts)
 
     def U_V(theta: float) -> float:
         if theta >= 1.0:
             return 0.0
-        n_lb = binomial_lower_bound(N, theta, delta2 / parts)
+        n_lb = binomial_lower_bound(N, theta, delta2 / parts, z=z_b)
         if n_lb <= 1.0:
             return math.inf
         return N * N * (1.0 - theta) * var_ub / n_lb
@@ -240,9 +243,10 @@ def naive_row_bounds(mean_p: float, var_p: float, n_p: int, theta_p: float,
     var_ub = (n_p - 1) / max(chi, 1e-12) * max(var_p, 0.0)
     L_N = exact_N if exact_N is not None else population_lower_bound(
         n_p, theta_p, delta2 / 3.0)
+    z_b = normal_ppf(1.0 - delta2 / 3.0)
 
     def U_V(theta: float) -> float:
-        n_lb = binomial_lower_bound(L_N, theta, delta2 / 3.0)
+        n_lb = binomial_lower_bound(L_N, theta, delta2 / 3.0, z=z_b)
         if n_lb <= 1:
             return math.inf
         return var_ub / n_lb

@@ -24,6 +24,8 @@ import threading
 import weakref
 from typing import Callable, Dict, List, Optional, Tuple
 
+from repro.stats import percentile_cache_info
+
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "GLOBAL",
     "register_session_collectors",
@@ -391,6 +393,10 @@ def register_session_collectors(registry: MetricsRegistry, session) -> None:
 
     registry.register_collector("compile_cache", compile_cache, owner=session)
     registry.register_collector("result_cache", result_cache, owner=session)
+    # process-wide memo of TAQA's percentiles (repro.stats): its hit share
+    # says how often a rate solve skips a scipy evaluation
+    registry.register_collector(
+        "percentiles", percentile_cache_info, owner=session)
     registry.register_collector("staged", staged, owner=session)
     registry.register_collector(
         "shard_scanned_bytes", shard_scanned_bytes, owner=session)

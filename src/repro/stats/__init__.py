@@ -4,6 +4,7 @@ from repro.stats.distributions import (
     chi2_ppf,
     binomial_lower_bound,
     population_lower_bound,
+    percentile_cache_info,
 )
 
 __all__ = [
@@ -12,4 +13,5 @@ __all__ = [
     "chi2_ppf",
     "binomial_lower_bound",
     "population_lower_bound",
+    "percentile_cache_info",
 ]

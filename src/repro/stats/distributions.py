@@ -11,11 +11,20 @@ Accuracy of the fallbacks (validated in tests/test_distributions.py):
   * student_t_ppf: Hill (1970) Cornish-Fisher expansion, rel err < 1e-3 for
     df >= 5 (TAQA requires pilot samples of n >= 30, see §3.1).
   * chi2_ppf: Wilson–Hilferty cube approximation, rel err < 1e-2 for df >= 20.
+
+Each percentile function is memoized by its arguments: a scipy ``ppf``
+costs about 0.1 ms of Python, and TAQA asks for the same few (p, df) pairs
+on every query (p comes from the query's confidence, df from the pilot's
+block count).  A memo hit returns the very float first computed, so every
+bound is the same number it would be uncached; a call that raises is never
+cached.  :func:`percentile_cache_info` counts the memo's hits and misses.
 """
 
 from __future__ import annotations
 
+import functools
 import math
+from typing import Dict, Optional
 
 import numpy as np
 
@@ -61,6 +70,12 @@ def _acklam(p: float) -> float:
         (((((_B[0] * r + _B[1]) * r + _B[2]) * r + _B[3]) * r + _B[4]) * r + 1)
 
 
+# lru_cache is thread-safe; 4096 entries lie far above the few dozen
+# (p, df) pairs a workload asks for, and bound the memo all the same.
+_memo = functools.lru_cache(maxsize=4096)
+
+
+@_memo
 def normal_ppf(p: float) -> float:
     """Percentile of the standard normal distribution (z_{p})."""
     if _HAVE_SCIPY:
@@ -72,6 +87,7 @@ def normal_ppf(p: float) -> float:
 # Student's t
 # ---------------------------------------------------------------------------
 
+@_memo
 def student_t_ppf(p: float, df: float) -> float:
     """Percentile t_{df, p} of Student's t distribution."""
     if df <= 0:
@@ -91,6 +107,7 @@ def student_t_ppf(p: float, df: float) -> float:
 # Chi-squared
 # ---------------------------------------------------------------------------
 
+@_memo
 def chi2_ppf(p: float, df: float) -> float:
     """Percentile chi2_{df, p}."""
     if df <= 0:
@@ -103,20 +120,32 @@ def chi2_ppf(p: float, df: float) -> float:
     return float(df * (1.0 - k + z * math.sqrt(k)) ** 3)
 
 
+def percentile_cache_info() -> Dict[str, int]:
+    """Memo totals over the three percentile functions: ``hits``,
+    ``misses`` (evaluations made) and ``size`` (values held)."""
+    infos = [f.cache_info() for f in (normal_ppf, student_t_ppf, chi2_ppf)]
+    return {"hits": sum(i.hits for i in infos),
+            "misses": sum(i.misses for i in infos),
+            "size": sum(i.currsize for i in infos)}
+
+
 # ---------------------------------------------------------------------------
 # Binomial / population-size bounds (Lemma B.1 machinery)
 # ---------------------------------------------------------------------------
 
-def binomial_lower_bound(n_units: float, theta: float, delta: float) -> float:
+def binomial_lower_bound(n_units: float, theta: float, delta: float,
+                         z: Optional[float] = None) -> float:
     """Probabilistic lower bound on a Bin(n_units, theta) sample size.
 
     Normal approximation (Ineq. 12 of the paper):
       P[n >= N*theta - z_{1-delta} sqrt(N theta (1-theta))] >= 1 - delta.
-    Clamped below at 0.
+    Clamped below at 0.  ``z``, when given, is ``normal_ppf(1 - delta)``
+    computed by the caller once for many ``theta``.
     """
     if n_units <= 0:
         return 0.0
-    z = normal_ppf(1.0 - delta)
+    if z is None:
+        z = normal_ppf(1.0 - delta)
     lo = n_units * theta - z * math.sqrt(max(n_units * theta * (1.0 - theta), 0.0))
     return max(lo, 0.0)
 

@@ -211,6 +211,122 @@ def test_naive_row_bounds_degenerate():
     assert L_mu == -math.inf and U_V(0.5) == math.inf
 
 
+# -- memoized percentiles: the rate solve is the uncached one, bit for bit ----
+#
+# The references below spell out the bounds with a fresh scipy evaluation
+# at every use, the binomial bound's z once per θ, as before the percentile
+# memo and the hoist out of U_V.
+
+def _sps():
+    from scipy import stats
+    return stats
+
+
+def _uncached_single_table_var_ub(y, theta_p, delta2, n_blocks):
+    sps = _sps()
+    n_p = y.shape[0]
+    parts = 2.0 if n_blocks is not None else 3.0
+    chi = float(sps.chi2.ppf(delta2 / parts, n_p - 1))
+    var_ub = (n_p - 1) / max(chi, 1e-12) * float(y.var(ddof=1))
+    if n_blocks is not None:
+        N = float(n_blocks)
+    else:  # population_lower_bound
+        z = float(sps.norm.ppf(1.0 - delta2 / parts))
+        c = z * z * (1.0 - theta_p) / (4.0 * theta_p)
+        root = math.sqrt(n_p / theta_p + c) - math.sqrt(c)
+        N = max(root * root, 0.0)
+
+    def U_V(theta):
+        if theta >= 1.0:
+            return 0.0
+        z = float(sps.norm.ppf(1.0 - delta2 / parts))
+        n_lb = max(N * theta - z * math.sqrt(max(N * theta * (1.0 - theta),
+                                                 0.0)), 0.0)
+        if n_lb <= 1.0:
+            return math.inf
+        return N * N * (1.0 - theta) * var_ub / n_lb
+
+    return U_V
+
+
+def _rate_solve(pair, n1, theta_p, exact_n, uncached):
+    """The constraints TAQA's `bounds` stage builds for one channel (one
+    table, or a two-table join when ``pair`` has columns), and the rates
+    `solve_candidates` picks from them."""
+    from repro.core.planner import Constraint, solve_candidates
+    sps = _sps()
+    y = pair.sum(axis=1)
+    n_p = y.shape[0]
+    b = allocate(0.95, 1, 0.05)
+    n_blocks = n1 if exact_n else None
+    if uncached:
+        t = float(sps.t.ppf(1.0 - b.delta1, n_p - 1))
+        L_mu = n1 * (float(y.mean()) - t * float(y.std(ddof=1))
+                     / math.sqrt(n_p))
+        z = float(sps.norm.ppf((1.0 + b.p_prime) / 2.0))
+        uv1 = _uncached_single_table_var_ub(y, theta_p, b.delta2, n_blocks)
+    else:
+        L_mu = n1 * bsap.block_mean_lower(y, b.delta1)
+        z = bsap.z_for(b.p_prime)
+        uv1 = bsap.single_table_var_ub(y, theta_p, b.delta2,
+                                       n_blocks=n_blocks)
+    with pytest.MonkeyPatch.context() as m:
+        if uncached:  # join_var_ub is unchanged; only its percentile memoized
+            m.setattr(bsap, "student_t_ppf",
+                      lambda p, df: float(sps.t.ppf(p, df)))
+        uv2 = bsap.join_var_ub(pair, n1, b.delta2) if pair.shape[1] > 1 \
+            else None
+
+    def var_fn(rates):
+        t1, t2 = rates.get("t1", 1.0), rates.get("t2", 1.0)
+        if uv2 is not None and t2 < 1.0:
+            return uv2(t1, t2)
+        return uv1(t1) if t1 < 1.0 else 0.0
+
+    tables = ["t1", "t2"] if uv2 is not None else ["t1"]
+    plans = solve_candidates(
+        [Constraint("c", z=z, L_mu=L_mu, error=b.error, var_fn=var_fn)],
+        tables)
+    return (L_mu, z, uv1, uv2), [p.rates for p in plans]
+
+
+@pytest.mark.parametrize("seed,n_p,n2,exact_n", [
+    (11, 30, 1, True), (12, 59, 1, True), (13, 200, 1, True),
+    (14, 59, 1, False), (15, 45, 8, True), (16, 120, 24, True)])
+def test_rate_solve_bit_identical_to_uncached_percentiles(
+        seed, n_p, n2, exact_n):
+    rng = np.random.default_rng(seed)
+    n1 = 117_188
+    pair = rng.gamma(4.0, 1.0, (n_p, n2))
+    theta_p = n_p / n1
+    new, new_rates = _rate_solve(pair, n1, theta_p, exact_n, False)
+    ref, ref_rates = _rate_solve(pair, n1, theta_p, exact_n, True)
+    assert new[:2] == ref[:2]
+    grid = np.geomspace(1e-6, 0.1, 97).tolist() + [0.5, 1.0]
+    assert [new[2](t) for t in grid] == [ref[2](t) for t in grid]
+    if n2 > 1:
+        assert ([new[3](a, b) for a in grid for b in grid]
+                == [ref[3](a, b) for a in grid for b in grid])
+    assert new_rates and new_rates == ref_rates
+
+
+def test_naive_row_bounds_bit_identical_to_uncached_percentiles():
+    sps = _sps()
+    mean_p, var_p, n_p, theta_p, d1, d2 = 3.5, 9.25, 87, 0.004, 0.01, 0.02
+    L_mu, U_V = bsap.naive_row_bounds(mean_p, var_p, n_p, theta_p, d1, d2)
+    t = float(sps.t.ppf(1.0 - d1, n_p - 1))
+    assert L_mu == mean_p - t * math.sqrt(var_p) / math.sqrt(n_p)
+    chi = float(sps.chi2.ppf(d2 / 3.0, n_p - 1))
+    var_ub = (n_p - 1) / max(chi, 1e-12) * var_p
+    z = float(sps.norm.ppf(1.0 - d2 / 3.0))
+    c = z * z * (1.0 - theta_p) / (4.0 * theta_p)
+    L_N = (math.sqrt(n_p / theta_p + c) - math.sqrt(c)) ** 2
+    for theta in np.geomspace(1e-5, 0.5, 61).tolist():
+        z = float(sps.norm.ppf(1.0 - d2 / 3.0))
+        n_lb = max(L_N * theta - z * math.sqrt(L_N * theta * (1 - theta)), 0)
+        assert U_V(theta) == (math.inf if n_lb <= 1 else var_ub / n_lb)
+
+
 # -- propagation rules (Table 2) -----------------------------------------------
 
 @settings(max_examples=200, deadline=None)

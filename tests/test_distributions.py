@@ -97,3 +97,86 @@ def test_bounds_zero_inputs():
     assert D.binomial_lower_bound(0, 0.5, 0.1) == 0.0
     assert D.population_lower_bound(0, 0.5, 0.1) == 0.0
     assert math.isfinite(D.population_lower_bound(100, 0.01, 0.05))
+
+
+# -- the percentile memo -------------------------------------------------------
+
+def _bounds_and_solve(y, n_blocks, delta2):
+    """What TAQA's `bounds` and `solve` stages ask of the percentiles for a
+    one-table, one-channel Q6: L_μ, z, U_V and the bisection over θ."""
+    from repro.core import bsap
+    from repro.core.planner import Constraint, solve_candidates
+    L_mu = n_blocks * bsap.block_mean_lower(y, delta2)
+    z = bsap.z_for(1.0 - 2.0 * delta2)
+    uv = bsap.single_table_var_ub(y, 0.0005, delta2, n_blocks=n_blocks)
+    c = Constraint("c", z=z, L_mu=L_mu, error=0.05,
+                   var_fn=lambda r: uv(r["t"]) if r["t"] < 1.0 else 0.0)
+    return uv, solve_candidates([c], ["t"])
+
+
+def test_percentile_memo_second_solve_evaluates_nothing():
+    rng = np.random.default_rng(21)
+    n_blocks, n_p, delta2 = 117_188, 61, 0.0061234  # a δ no other test uses
+    _, plans = _bounds_and_solve(rng.gamma(4.0, 1.0, n_p), n_blocks, delta2)
+    assert plans
+    before = D.percentile_cache_info()
+    uv, plans = _bounds_and_solve(rng.gamma(4.0, 1.0, n_p), n_blocks, delta2)
+    after = D.percentile_cache_info()
+    assert plans
+    assert after["misses"] == before["misses"]
+    assert after["size"] == before["size"]
+    # t for L_μ, z, chi² and the binomial z for U_V; none in the bisection
+    assert after["hits"] - before["hits"] == 4
+    for theta in np.geomspace(1e-6, 0.1, 49):
+        uv(float(theta))
+    assert D.percentile_cache_info() == after
+
+
+def test_percentile_memo_never_caches_a_bad_argument(monkeypatch):
+    size = D.percentile_cache_info()["size"]
+    for _ in range(2):
+        with pytest.raises(ValueError):
+            D.student_t_ppf(0.9, 0)
+        with pytest.raises(ValueError):
+            D.chi2_ppf(0.9, -1)
+    # the closed forms check p as well
+    monkeypatch.setattr(D, "_HAVE_SCIPY", False)
+    for _ in range(2):
+        with pytest.raises(ValueError):
+            D.normal_ppf(1.25)
+        with pytest.raises(ValueError):
+            D.student_t_ppf(-0.5, 10)
+        with pytest.raises(ValueError):
+            D.chi2_ppf(1.5, 10)
+    assert D.percentile_cache_info()["size"] == size
+
+
+def test_percentile_memo_threads_agree_with_scipy():
+    import sys
+    import threading
+    from concurrent.futures import ThreadPoolExecutor
+    sps = pytest.importorskip("scipy.stats")
+    args = [(0.9 + k * 1e-3 + 1e-7, 30 + k) for k in range(64)]
+    barrier = threading.Barrier(4)
+
+    def work():
+        barrier.wait(timeout=30)
+        return [(D.normal_ppf(p), D.student_t_ppf(p, df),
+                 D.chi2_ppf(1.0 - p, df)) for p, df in args]
+
+    before = D.percentile_cache_info()
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with ThreadPoolExecutor(4) as pool:
+            futures = [pool.submit(work) for _ in range(4)]
+            results = [f.result(timeout=60) for f in futures]
+    finally:
+        sys.setswitchinterval(interval)
+    after = D.percentile_cache_info()
+    expected = [(float(sps.norm.ppf(p)), float(sps.t.ppf(p, df)),
+                 float(sps.chi2.ppf(1.0 - p, df))) for p, df in args]
+    assert all(r == expected for r in results)
+    # every lookup counted once: no update of the memo's counters was lost
+    assert (after["hits"] + after["misses"]
+            - before["hits"] - before["misses"]) == 4 * 3 * len(args)

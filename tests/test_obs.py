@@ -545,6 +545,21 @@ def test_drain_counters_land_in_registry(catalog):
     s.close()
 
 
+def test_percentiles_collector_counts_memo_hits(catalog):
+    from repro.stats import percentile_cache_info
+    s = Session(catalog, seed=5, config=NOCACHE_CFG)
+    before = s.metrics.tree()["percentiles"]
+    assert set(before) == {"hits", "misses", "size"}
+    for _ in range(2):  # the second drain's solves find every percentile
+        for _ in range(4):
+            s.submit(HERD_SQL)
+        s.drain()
+    after = s.metrics.tree()["percentiles"]
+    assert after == percentile_cache_info()
+    assert after["hits"] >= before["hits"] + 4
+    s.close()
+
+
 def test_gateway_metrics_text_includes_gateway_counters(catalog):
     s = Session(catalog, seed=5)
     gw = SqlGateway(s)
