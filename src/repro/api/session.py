@@ -1202,10 +1202,8 @@ class Session:
             gen = self._scan_generations(handle.query)
             try:
                 pilot_est = None
-                if handle.spec is None:
-                    with _trace.span("exact") as sp:
-                        ans = self.db.exact(handle.query)
-                        sp.set(scanned_bytes=ans.report.exact_scanned_bytes)
+                if handle.spec is None:  # db.exact holds the exact span
+                    ans = self.db.exact(handle.query)
                 elif self.config.fused_taqa and (
                         fused := self._run_fused(handle)) is not None:
                     ans = fused

@@ -13,6 +13,7 @@ exact execution — PilotDB never returns an unguaranteed estimate.
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -311,6 +312,10 @@ class PilotDB:
     def __init__(self, executor: Executor, large_table_rows: int = 50_000):
         self.ex = executor
         self.large_table_rows = large_table_rows
+        # exact answers by reason (see :meth:`exact_answer_counts`); the
+        # runtime answers queries from several worker threads
+        self._exact_lock = threading.Lock()
+        self._exact_answers: Dict[str, int] = {}
 
     # -- helpers -------------------------------------------------------------
     def _engine_plan(self, q: Query) -> Tuple[L.Aggregate, List[Tuple[int, ...]]]:
@@ -326,12 +331,24 @@ class PilotDB:
     def _exact(self, q: Query, plan: L.Aggregate, comp_channels, report: TaqaReport,
                reason: str) -> ApproxAnswer:
         report.fallback = reason
-        t0 = time.perf_counter()
-        res = self.ex.execute(L.strip_samples(plan))
-        report.final_time_s = time.perf_counter() - t0
+        with _trace.span("exact", reason=reason) as sp:
+            t0 = time.perf_counter()
+            res = self.ex.execute(L.strip_samples(plan))
+            report.final_time_s = time.perf_counter() - t0
+            sp.set(n_blocks=_blocks_read(res), bytes=res.scanned_bytes)
         report.final_scanned_bytes = res.scanned_bytes
+        key = reason.split(" (", 1)[0]
+        with self._exact_lock:
+            self._exact_answers[key] = self._exact_answers.get(key, 0) + 1
         values = _combine(q, comp_channels, res.values)
         return ApproxAnswer([c.name for c in q.aggs], values, res.group_present, report)
+
+    def exact_answer_counts(self) -> Dict[str, int]:
+        """Exact answers given so far, by reason: the fallback reason
+        without its parenthesised detail (which aggregate, group or table),
+        so that the keys stay few."""
+        with self._exact_lock:
+            return dict(self._exact_answers)
 
     # -- the two-stage algorithm ----------------------------------------------
     def query(self, q: Query, spec: ErrorSpec, seed: int = 0,

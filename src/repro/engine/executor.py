@@ -509,11 +509,9 @@ class Executor:
         for a in plan.aggs:
             names.append(a.name)
             exprs.append(None if a.op == "count" else a.expr)
-        sums = np.asarray(
-            ops.grouped_sums(table, exprs, plan.group_by, plan.max_groups),
-            dtype=np.float64)
-        counts = np.asarray(
-            ops.grouped_counts(table, plan.group_by, plan.max_groups), dtype=np.float64)
+        sums, counts = (np.asarray(x, dtype=np.float64) for x in
+                        ops.grouped_totals(table, exprs, plan.group_by,
+                                           plan.max_groups))
 
         self._check_empty(infos)
         values = self._compose_values(plan, sums, counts, self._upscale(infos))

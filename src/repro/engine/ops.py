@@ -83,36 +83,18 @@ def union_all(tables: list[BlockTable]) -> BlockTable:
 # Aggregation
 # ---------------------------------------------------------------------------
 
-def _agg_values(table: BlockTable, expr: Optional[Expr]) -> jnp.ndarray:
-    if expr is None:
-        vals = jnp.ones(table.padded_rows, dtype=jnp.float32)
-    else:
-        vals = eval_expr(expr, table.columns).astype(jnp.float32)
-    return jnp.where(table.valid, vals, 0.0)
+def grouped_totals(table: BlockTable, exprs, group_by: Optional[str],
+                   max_groups: int):
+    """(sums (num_aggs, max_groups), counts (max_groups,)): each expr's sum
+    and the valid rows per group, summed as the compiled route sums them
+    (``physical.blockwise_totals``, the counts as a trailing COUNT
+    channel), so that the two routes agree bit for bit."""
+    from repro.engine import physical
 
-
-def group_ids(table: BlockTable, group_by: Optional[str], max_groups: int) -> jnp.ndarray:
-    if group_by is None:
-        return jnp.zeros(table.padded_rows, dtype=jnp.int32)
-    gid = table.columns[group_by].astype(jnp.int32)
-    return jnp.clip(gid, 0, max_groups - 1)
-
-
-def grouped_sums(table: BlockTable, exprs, group_by: Optional[str],
-                 max_groups: int) -> jnp.ndarray:
-    """Returns (num_aggs, max_groups) sums of each expr per group."""
-    gid = group_ids(table, group_by, max_groups)
-    outs = []
-    for expr in exprs:
-        vals = _agg_values(table, expr)
-        outs.append(jnp.zeros(max_groups, jnp.float32).at[gid].add(vals))
-    return jnp.stack(outs)
-
-
-def grouped_counts(table: BlockTable, group_by: Optional[str], max_groups: int) -> jnp.ndarray:
-    gid = group_ids(table, group_by, max_groups)
-    return jnp.zeros(max_groups, jnp.float32).at[gid].add(
-        table.valid.astype(jnp.float32))
+    totals = physical.blockwise_totals(
+        table.columns, table.valid, tuple(exprs) + (None,), group_by,
+        max_groups, table.block_rows, None)
+    return totals[:-1], totals[-1]
 
 
 def block_group_sums(table: BlockTable, exprs, group_by: Optional[str],

@@ -38,9 +38,12 @@ allows:
 * ``pallas_block``   — filterless ``Aggregate(Scan)`` with SUM(col)/COUNT
   channels lowers onto :func:`repro.kernels.block_agg.block_agg`.
 * ``xla_gather``     — everything else (joins, unions, GROUP BY, composite
-  expressions) lowers to the kernels' XLA twin: a device-side slab gather
-  with static (bucketed) shape followed by one fused multi-channel
-  scatter-add.  Same semantics, one graph, no host round-trips.
+  expressions, exact scans) lowers to the kernels' XLA twin: a device-side
+  slab gather with static (bucketed) shape, per-(block, group) channel sums,
+  then a pairwise sum over the blocks (:func:`blockwise_totals`).  Same
+  semantics, one graph, no host round-trips.  An exact scan (every table
+  unsampled) is jitted as ``run_exact``, so the device trace names it
+  ``jit_run_exact``.
 
 Pallas routes are selected on TPU backends (``kernel_mode="auto"``) where the
 kernels compile to real DMA programs; on CPU containers interpret mode would
@@ -75,6 +78,10 @@ from repro.obs import trace as _trace
 
 _BIG_BOUND = 3.0e38       # "unbounded" predicate slot, f32-safe
 _INT_MAX = np.int32(2 ** 31 - 1)
+# The XLA route's per-(block, group) partial sums held on the device at
+# once, in bytes.  A table whose partials would exceed it is summed run of
+# blocks by run of blocks, each run reduced before the next starts.
+PARTIALS_BUDGET_BYTES = 64 << 20
 
 
 # ---------------------------------------------------------------------------
@@ -213,16 +220,15 @@ def _needed_by_table(plan: L.Plan, catalog: Dict[str, BlockTable]) -> Dict[str, 
 # Fused multi-channel aggregation primitives (the XLA twin of the kernels)
 # ---------------------------------------------------------------------------
 
-def channel_matrix(columns: Dict[str, jnp.ndarray], valid: jnp.ndarray,
+def channel_values(columns: Dict[str, jnp.ndarray], valid: jnp.ndarray,
                    exprs: Sequence[Optional[Expr]],
-                   params=None) -> jnp.ndarray:
-    """Stack every aggregate channel's per-row values: (num_channels, rows).
+                   params=None) -> List[jnp.ndarray]:
+    """Every aggregate channel's per-row values, one (rows,) array each.
 
-    ``None`` channels are COUNT (ones).  Invalid rows contribute zeros, so a
-    single scatter-add over the stacked matrix replaces the legacy
-    per-expression Python loop.  ``params`` resolves hoisted-constant Param
-    slots in template expressions (compiled lowerings); eager callers pass
-    constant-bearing exprs and omit it.
+    ``None`` channels are COUNT (ones).  Invalid rows contribute zeros.
+    ``params`` resolves hoisted-constant Param slots in template
+    expressions (compiled lowerings); eager callers pass constant-bearing
+    exprs and omit it.
     """
     rows = valid.shape[0]
     outs = []
@@ -233,7 +239,15 @@ def channel_matrix(columns: Dict[str, jnp.ndarray], valid: jnp.ndarray,
             v = jnp.broadcast_to(
                 eval_expr(e, columns, params).astype(jnp.float32), (rows,))
         outs.append(jnp.where(valid, v, 0.0))
-    return jnp.stack(outs)
+    return outs
+
+
+def channel_matrix(columns: Dict[str, jnp.ndarray], valid: jnp.ndarray,
+                   exprs: Sequence[Optional[Expr]],
+                   params=None) -> jnp.ndarray:
+    """:func:`channel_values` stacked, (num_channels, rows), so that a
+    single scatter-add over the matrix sums every channel."""
+    return jnp.stack(channel_values(columns, valid, exprs, params))
 
 
 @functools.partial(jax.jit, static_argnames=("exprs", "group_by", "max_groups", "n_origin"))
@@ -275,6 +289,74 @@ def dense_block_pair_sums(columns, valid, block_id, lblock_ids, *, exprs: tuple,
     vals = channel_matrix(columns, valid, exprs)
     dense = jnp.zeros((len(exprs), (n_p + 1) * n_right), jnp.float32).at[:, seg].add(vals)
     return dense.reshape(len(exprs), n_p + 1, n_right)[:, :n_p]
+
+
+def pairwise_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum over axis 0 as a balanced tree: the axis is zero-padded to a
+    power of two and halved until one row is left, so each addend passes
+    through log2(n) float32 additions, not up to n as in a running sum."""
+    n = x.shape[0]
+    size = 1 << max(n - 1, 0).bit_length()
+    if size != n:
+        x = jnp.concatenate([x, jnp.zeros((size - n,) + x.shape[1:], x.dtype)])
+    while x.shape[0] > 1:
+        half = x.shape[0] // 2
+        x = x[:half] + x[half:]
+    return x[0]
+
+
+def blockwise_totals(columns: Dict[str, jnp.ndarray], valid: jnp.ndarray,
+                     exprs: Tuple[Optional[Expr], ...],
+                     group_by: Optional[str], max_groups: int,
+                     block_rows: int, params) -> jnp.ndarray:
+    """Per-group channel totals, (len(exprs), max_groups), of block-aligned
+    rows: each block's rows are summed in float32 into per-(block, group)
+    partials (no accumulator takes more than one block's rows), and the
+    partials are added by :func:`pairwise_sum`.  When the partials would
+    exceed :data:`PARTIALS_BUDGET_BYTES`, the blocks go through in runs,
+    one run's partials at a time, and the runs' totals are added pairwise
+    too."""
+    n_ch, mg = len(exprs), max_groups
+    nb = valid.shape[0] // block_rows
+
+    def partials(cols, ok):  # k blocks' rows -> (k, n_ch, mg)
+        k = ok.shape[0] // block_rows
+        if group_by is None:
+            # a block as (rows / 128, 128): on a TPU a 1024-row block is one
+            # (8, 128) tile of the column, so the reshape moves no data; one
+            # variadic reduce sums every channel in a single pass
+            lanes = 128 if block_rows % 128 == 0 else block_rows
+            vals = [v.reshape(k, block_rows // lanes, lanes)
+                    for v in channel_values(cols, ok, exprs, params)]
+            sums = jax.lax.reduce(
+                vals, [jnp.float32(0)] * n_ch,
+                lambda xs, ys: [x + y for x, y in zip(xs, ys)], (1, 2))
+            return jnp.stack(sums, axis=1)[:, :, None]
+        vals = channel_matrix(cols, ok, exprs, params)
+        gid = jnp.clip(cols[group_by].astype(jnp.int32), 0, mg - 1)
+        seg = (jnp.arange(k * block_rows, dtype=jnp.int32) // block_rows
+               * mg + gid)
+        dense = jnp.zeros((n_ch, k * mg), jnp.float32).at[:, seg].add(vals)
+        return dense.reshape(n_ch, k, mg).transpose(1, 0, 2)
+
+    run = max(1, min(nb, PARTIALS_BUDGET_BYTES // (n_ch * mg * 4)))
+    if run >= nb:
+        return pairwise_sum(partials(columns, valid))
+
+    def one(i):
+        # the last run is clamped to the table's end; blocks an earlier run
+        # already summed are masked out of it
+        start = jnp.minimum(i * run, nb - run)
+
+        def cut(x):
+            return jax.lax.dynamic_slice_in_dim(x, start * block_rows,
+                                                run * block_rows)
+
+        part = partials({c: cut(v) for c, v in columns.items()}, cut(valid))
+        fresh = start + jnp.arange(run) >= i * run
+        return pairwise_sum(jnp.where(fresh[:, None, None], part, 0.0))
+
+    return pairwise_sum(jax.lax.map(one, jnp.arange(-(-nb // run))))
 
 
 # ---------------------------------------------------------------------------
@@ -886,23 +968,27 @@ class PhysicalCompiler:
 
         def run(rt):
             tt = tracer.trace(template.child, rt)
-            rows = tt.valid.shape[0]
-            if template.group_by is None:
-                gid = jnp.zeros(rows, jnp.int32)
-            else:
-                gid = jnp.clip(tt.columns[template.group_by].astype(jnp.int32),
-                               0, mg - 1)
-            vals = channel_matrix(tt.columns, tt.valid, exprs, rt["params"])
-            sums = jnp.zeros((len(exprs), mg), jnp.float32).at[:, gid].add(vals)
-            counts = jnp.zeros(mg, jnp.float32).at[gid].add(tt.valid.astype(jnp.float32))
-            return sums, counts
+            # the trailing COUNT channel gives the per-group row counts
+            totals = blockwise_totals(tt.columns, tt.valid, exprs + (None,),
+                                      template.group_by, mg, tt.block_rows,
+                                      rt["params"])
+            return totals[:-1], totals[-1]
 
         return run, "xla_gather"
 
     def _build_query(self, template, runtimes, needed) -> CompiledQuery:
         methods = {t: r.method for t, r in runtimes.items()}
         run, route = self._query_run_fn(template, runtimes, needed)
-        return CompiledQuery(fn=jax.jit(run), catalog=self.catalog, needed=needed,
+        if all(m == "none" for m in methods.values()):
+            # the exact scan gets a jit name of its own (``jit_run_exact``
+            # in the device trace), apart from the sampled scans' ``jit_run``
+            def run_exact(rt):
+                return run(rt)
+
+            fn = jax.jit(run_exact)
+        else:
+            fn = jax.jit(run)
+        return CompiledQuery(fn=fn, catalog=self.catalog, needed=needed,
                              methods=methods, route=route)
 
     # -- batched drain-group queries -----------------------------------------

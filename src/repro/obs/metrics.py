@@ -367,6 +367,13 @@ def register_session_collectors(registry: MetricsRegistry, session) -> None:
             out.update(rt.totals())
         return out
 
+    def exact_answers() -> Dict:
+        s = ref()
+        db = getattr(s, "db", None) if s is not None else None
+        if db is None:
+            return {}
+        return db.exact_answer_counts()
+
     def audit() -> Dict:
         s = ref()
         auditor = getattr(s, "auditor", None) if s is not None else None
@@ -401,6 +408,8 @@ def register_session_collectors(registry: MetricsRegistry, session) -> None:
     registry.register_collector(
         "shard_scanned_bytes", shard_scanned_bytes, owner=session)
     registry.register_collector("runtime", runtime, owner=session)
+    # TAQA's exact answers by fallback reason (``core/taqa.py`` ``_exact``)
+    registry.register_collector("exact_answers", exact_answers, owner=session)
     registry.register_collector("audit", audit, owner=session)
     registry.register_collector("timeseries", timeseries, owner=session)
     registry.register_collector("slo", slo, owner=session)

@@ -94,3 +94,40 @@ def test_id_table_past_smem_splits_and_compiles(one_chip):
     _compile(lambda x, y, a, b, c, v, i, bd: filtered_agg_batched(
         x, y, a, b, c, v, BLOCK_ROWS, i, bd, interpret=False),
         *_q6_args(one_chip, _ids(one_chip, 8, 1 << 16), (8, 5)))
+
+
+def test_exact_scan_compiles_for_v5e_without_row_temporaries(one_chip):
+    """The exact Q6 scan of the XLA route sums each 1024-row block straight
+    from the columns: compiled for a v5e, it holds no temporary of row
+    length (one float32 channel over SF10 is 240 MB; the block partials and
+    their pairwise sum are a few MB)."""
+    import types
+
+    from repro.engine import logical as L
+    from repro.engine.expr import And, Col
+    from repro.engine.physical import PhysicalCompiler, ScanRuntime
+
+    rows = SF10_BLOCKS * BLOCK_ROWS
+    dtypes = {"l_shipdate": jnp.int32, "l_discount": jnp.float32,
+              "l_quantity": jnp.float32, "l_extendedprice": jnp.float32}
+    table = types.SimpleNamespace(
+        block_rows=BLOCK_ROWS, padded_rows=rows, num_blocks=SF10_BLOCKS,
+        num_origin_blocks=SF10_BLOCKS,
+        columns={c: _col(one_chip, d) for c, d in dtypes.items()})
+    pred = And(Col("l_shipdate").between(100, 464),
+               And(Col("l_discount").between(0.05, 0.07),
+                   Col("l_quantity") < 24))
+    plan = L.Aggregate(L.Filter(L.Scan("lineitem"), pred), (
+        L.AggSpec("sum", Col("l_extendedprice") * Col("l_discount"), "rev"),))
+    compiled = PhysicalCompiler({"lineitem": table}, kernel_mode="xla") \
+        .compile_query(plan, {"lineitem": ScanRuntime("none")})
+    rt = {"cols": {"lineitem": dict(table.columns)},
+          "valid": {"lineitem": _col(one_chip, jnp.bool_)},
+          "bid": {"lineitem": _col(one_chip, jnp.int32)},
+          "ids": {}, "nreal": {}, "mask": {},
+          "params": jax.ShapeDtypeStruct((5,), jnp.float32,
+                                         sharding=one_chip)}
+    lowered = compiled.fn.lower(rt)
+    assert "@jit_run_exact" in lowered.as_text()
+    temp = lowered.compile().memory_analysis().temp_size_in_bytes
+    assert temp < 16 << 20, temp

@@ -129,8 +129,9 @@ def test_union_all_offsets_block_ids():
 
 def test_grouped_sums_and_counts():
     t = small_table(n=64, br=8)
-    sums = np.asarray(ops.grouped_sums(t, [Col("x")], "g", 3))[0]
-    counts = np.asarray(ops.grouped_counts(t, "g", 3))
+    sums, counts = (np.asarray(x) for x in
+                    ops.grouped_totals(t, [Col("x")], "g", 3))
+    sums = sums[0]
     x = np.asarray(t.columns["x"])[:64]
     g = np.asarray(t.columns["g"])[:64]
     for gid in range(3):
