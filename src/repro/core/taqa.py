@@ -312,10 +312,12 @@ class PilotDB:
     def __init__(self, executor: Executor, large_table_rows: int = 50_000):
         self.ex = executor
         self.large_table_rows = large_table_rows
-        # exact answers by reason (see :meth:`exact_answer_counts`); the
+        # exact answers by reason and by physical route (see
+        # :meth:`exact_answer_counts`, :meth:`exact_route_counts`); the
         # runtime answers queries from several worker threads
         self._exact_lock = threading.Lock()
         self._exact_answers: Dict[str, int] = {}
+        self._exact_routes: Dict[str, int] = {}
 
     # -- helpers -------------------------------------------------------------
     def _engine_plan(self, q: Query) -> Tuple[L.Aggregate, List[Tuple[int, ...]]]:
@@ -335,11 +337,14 @@ class PilotDB:
             t0 = time.perf_counter()
             res = self.ex.execute(L.strip_samples(plan))
             report.final_time_s = time.perf_counter() - t0
-            sp.set(n_blocks=_blocks_read(res), bytes=res.scanned_bytes)
+            sp.set(n_blocks=_blocks_read(res), bytes=res.scanned_bytes,
+                   route=res.route)
         report.final_scanned_bytes = res.scanned_bytes
         key = reason.split(" (", 1)[0]
         with self._exact_lock:
             self._exact_answers[key] = self._exact_answers.get(key, 0) + 1
+            self._exact_routes[res.route] = (
+                self._exact_routes.get(res.route, 0) + 1)
         values = _combine(q, comp_channels, res.values)
         return ApproxAnswer([c.name for c in q.aggs], values, res.group_present, report)
 
@@ -349,6 +354,13 @@ class PilotDB:
         so that the keys stay few."""
         with self._exact_lock:
             return dict(self._exact_answers)
+
+    def exact_route_counts(self) -> Dict[str, int]:
+        """Exact answers given so far, by the physical route of their scan
+        (``pallas_exact``, ``xla_gather``, ...): how often the exact-scan
+        kernel engages."""
+        with self._exact_lock:
+            return dict(self._exact_routes)
 
     # -- the two-stage algorithm ----------------------------------------------
     def query(self, q: Query, spec: ErrorSpec, seed: int = 0,

@@ -72,6 +72,9 @@ class QueryResult:
     scanned_bytes: int
     sample_infos: Dict[str, SampleInfo]
     wall_time_s: float
+    # the physical route that computed it (``CompiledQuery.route``, or
+    # "eager" for the interpreter); None where no single route did
+    route: Optional[str] = None
 
     def scalar(self, name: str, group: int = 0) -> float:
         return float(self.values[self.agg_names.index(name), group])
@@ -451,6 +454,7 @@ class Executor:
             scanned_bytes=compiled.scanned_bytes(runtimes),
             sample_infos=infos,
             wall_time_s=time.perf_counter() - t0,
+            route=compiled.route,
         )
 
     def _execute_compiled(self, plan: L.Aggregate) -> QueryResult:
@@ -472,6 +476,7 @@ class Executor:
             scanned_bytes=compiled.scanned_bytes(runtimes),
             sample_infos=infos,
             wall_time_s=time.perf_counter() - t0,
+            route=compiled.route,
         )
 
     def _draw(self, plan: L.Aggregate):
@@ -525,6 +530,7 @@ class Executor:
             scanned_bytes=scanned,
             sample_infos=infos,
             wall_time_s=time.perf_counter() - t0,
+            route="eager",
         )
 
     # -- batched execution (drain-group finals) ------------------------------
@@ -705,6 +711,7 @@ class Executor:
                 scanned_bytes=compiled.scanned_bytes(runtimes),
                 sample_infos=infos,
                 wall_time_s=wall,
+                route=compiled.route,
             )
 
     def execute_pilot(

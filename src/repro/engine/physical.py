@@ -37,13 +37,24 @@ allows:
   the scan pays θ·bytes, not bytes.
 * ``pallas_block``   — filterless ``Aggregate(Scan)`` with SUM(col)/COUNT
   channels lowers onto :func:`repro.kernels.block_agg.block_agg`.
+* ``pallas_exact``   — the ``pallas_filtered`` shape over an *unsampled*
+  table (an exact answer) lowers onto
+  :func:`repro.kernels.exact_agg.exact_agg`: the grid streams contiguous
+  groups of blocks in order, each column in its own dtype (``valid`` as
+  the table's int8 copy, :meth:`BlockTable.valid_bytes`), with the
+  predicate and channels the ``xla_gather`` route would evaluate; the
+  per-step sums are added by :func:`pairwise_sum`.
 * ``xla_gather``     — everything else (joins, unions, GROUP BY, composite
-  expressions, exact scans) lowers to the kernels' XLA twin: a device-side
-  slab gather with static (bucketed) shape, per-(block, group) channel sums,
-  then a pairwise sum over the blocks (:func:`blockwise_totals`).  Same
-  semantics, one graph, no host round-trips.  An exact scan (every table
-  unsampled) is jitted as ``run_exact``, so the device trace names it
-  ``jit_run_exact``.
+  expressions, the other exact scans) lowers to the kernels' XLA twin: a
+  device-side slab gather with static (bucketed) shape, per-(block, group)
+  channel sums, then a pairwise sum over the blocks
+  (:func:`blockwise_totals`).  Same semantics, one graph, no host
+  round-trips.
+
+An exact scan (every table unsampled), on either route, is jitted as
+``run_exact``, so the device trace names it ``jit_run_exact``.  The batched
+and fused lowerings never take a Pallas route of a solo query's
+(``allow_kernel=False``).
 
 Pallas routes are selected on TPU backends (``kernel_mode="auto"``) where the
 kernels compile to real DMA programs; on CPU containers interpret mode would
@@ -73,6 +84,8 @@ from repro.engine import logical as L
 from repro.engine.expr import And, Between, BinOp, Cmp, Col, Expr, eval_expr
 from repro.engine.table import BlockTable
 from repro.kernels.block_agg import block_agg, block_agg_batched
+from repro.kernels.exact_agg import exact_agg
+from repro.kernels.exact_agg.kernel import MAX_CHANNELS as exact_agg_max_channels
 from repro.kernels.filtered_agg import filtered_agg, filtered_agg_batched
 from repro.obs import trace as _trace
 
@@ -223,21 +236,21 @@ def _needed_by_table(plan: L.Plan, catalog: Dict[str, BlockTable]) -> Dict[str, 
 def channel_values(columns: Dict[str, jnp.ndarray], valid: jnp.ndarray,
                    exprs: Sequence[Optional[Expr]],
                    params=None) -> List[jnp.ndarray]:
-    """Every aggregate channel's per-row values, one (rows,) array each.
+    """Every aggregate channel's per-row values, one array of ``valid``'s
+    shape each (rows, or a kernel's (rows / 128, 128) chunk).
 
     ``None`` channels are COUNT (ones).  Invalid rows contribute zeros.
     ``params`` resolves hoisted-constant Param slots in template
     expressions (compiled lowerings); eager callers pass constant-bearing
     exprs and omit it.
     """
-    rows = valid.shape[0]
     outs = []
     for e in exprs:
         if e is None:
-            v = jnp.ones(rows, jnp.float32)
+            v = jnp.ones(valid.shape, jnp.float32)
         else:
             v = jnp.broadcast_to(
-                eval_expr(e, columns, params).astype(jnp.float32), (rows,))
+                eval_expr(e, columns, params).astype(jnp.float32), valid.shape)
         outs.append(jnp.where(valid, v, 0.0))
     return outs
 
@@ -620,7 +633,10 @@ class _CompiledBase:
         for name in self.needed:
             tab = self.catalog[name]
             rt["cols"][name] = {c: tab.columns[c] for c in self.needed[name]}
-            rt["valid"][name] = tab.valid
+            # the exact-scan kernel reads ``valid`` as the table's int8 copy
+            rt["valid"][name] = (tab.valid_bytes()
+                                 if self.route == "pallas_exact"
+                                 else tab.valid)
             rt["bid"][name] = tab.block_id
         return rt
 
@@ -959,8 +975,13 @@ class PhysicalCompiler:
         exprs = tuple(None if a.op == "count" else a.expr for a in template.aggs)
         mg = template.max_groups
 
-        kernel = (self._match_query_kernel(template, runtimes, exprs)
-                  if allow_kernel and self._use_pallas() else None)
+        kernel = None
+        if allow_kernel and self._use_pallas():
+            if all(m == "none" for m in methods.values()):
+                kernel = self._match_exact_kernel(template, runtimes, needed,
+                                                  exprs)
+            else:
+                kernel = self._match_query_kernel(template, runtimes, exprs)
         if kernel is not None:
             return kernel
 
@@ -1072,6 +1093,48 @@ class PhysicalCompiler:
             return ch.sum(axis=0)[:, None], cnt.sum()[None]
 
         return run, route
+
+    def _match_exact_kernel(self, plan, runtimes, needed, exprs):
+        """Whole-query kernel route of an exact scan: one unsampled table,
+        no groups, the predicates ``pallas_filtered`` takes
+        (:func:`_match_q6_bounds`) and its channels (:func:`_match_channels`,
+        one fewer than the kernel's lanes, for the row count), rows a
+        multiple of 128.
+
+        The kernel evaluates the plan's own predicates and channels
+        (:func:`eval_expr`, :func:`channel_values`) on each chunk, exactly
+        as the ``xla_gather`` route does on the whole column: an int32
+        column meets its float32 bounds in float32 on both routes.
+        """
+        if plan.max_groups != 1 or plan.group_by is not None:
+            return None
+        if len(runtimes) != 1:
+            return None
+        (table,) = runtimes
+        preds = _single_table_chain(plan.child, table)
+        if (not preds or _match_q6_bounds(preds) is None
+                or _match_channels(exprs, products=True) is None):
+            return None
+        if (self.catalog[table].padded_rows % 128
+                or len(exprs) >= exact_agg_max_channels):
+            return None
+        names = needed[table]
+        channels = exprs + (None,)  # the trailing COUNT gives the row count
+
+        def channels_fn(cols, ok, params):
+            cols = dict(zip(names, cols))
+            for p in preds:
+                ok = ok & eval_expr(p, cols, params)
+            return channel_values(cols, ok, channels, params)
+
+        def run(rt):
+            cols = rt["cols"][table]
+            parts = exact_agg([cols[c] for c in names], rt["valid"][table],
+                              rt["params"], channels_fn, len(channels))
+            totals = pairwise_sum(parts)
+            return totals[:-1, None], totals[-1:]
+
+        return run, "pallas_exact"
 
     def _match_batched_query_kernel(self, plan, runtimes):
         """Batched whole-query kernel route (the megacore-style grid).

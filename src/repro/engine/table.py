@@ -21,10 +21,14 @@ Rows carry two pieces of lineage that BSAP needs:
 from __future__ import annotations
 
 import dataclasses
+import threading
 from typing import Dict, Optional
 
 import jax.numpy as jnp
 import numpy as np
+
+
+_VALID_BYTES_LOCK = threading.Lock()
 
 
 @dataclasses.dataclass
@@ -38,6 +42,9 @@ class BlockTable:
     valid: Optional[jnp.ndarray] = None  # bool, same shape as columns
     block_id: Optional[jnp.ndarray] = None  # int32 origin block per row
     num_origin_blocks: Optional[int] = None  # blocks in the *base* table
+    # ``valid`` as int8, made on first use (see :meth:`valid_bytes`)
+    _valid_bytes: Optional[jnp.ndarray] = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
 
     def __post_init__(self):
         n = self.padded_rows
@@ -68,6 +75,19 @@ class BlockTable:
     @property
     def column_names(self):
         return list(self.columns.keys())
+
+    def valid_bytes(self) -> jnp.ndarray:
+        """``valid`` as int8 (1 live, 0 not), made once and kept with the
+        table: a Pallas kernel cannot take a bool operand without a cast of
+        the whole column to int32 on every call.  A table made by
+        :meth:`with_valid` (or any other copy) makes its own."""
+        if self._valid_bytes is None:
+            # worker threads meet a table's first exact scan together: one
+            # makes the copy, the others take it
+            with _VALID_BYTES_LOCK:
+                if self._valid_bytes is None:
+                    self._valid_bytes = self.valid.astype(jnp.int8)
+        return self._valid_bytes
 
     def row_bytes(self) -> int:
         return sum(int(np.dtype(c.dtype).itemsize) for c in self.columns.values())

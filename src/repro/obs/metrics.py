@@ -374,6 +374,13 @@ def register_session_collectors(registry: MetricsRegistry, session) -> None:
             return {}
         return db.exact_answer_counts()
 
+    def exact_routes() -> Dict:
+        s = ref()
+        db = getattr(s, "db", None) if s is not None else None
+        if db is None:
+            return {}
+        return db.exact_route_counts()
+
     def audit() -> Dict:
         s = ref()
         auditor = getattr(s, "auditor", None) if s is not None else None
@@ -410,6 +417,8 @@ def register_session_collectors(registry: MetricsRegistry, session) -> None:
     registry.register_collector("runtime", runtime, owner=session)
     # TAQA's exact answers by fallback reason (``core/taqa.py`` ``_exact``)
     registry.register_collector("exact_answers", exact_answers, owner=session)
+    # ... and by the physical route of their scan (``res.route``)
+    registry.register_collector("exact_routes", exact_routes, owner=session)
     registry.register_collector("audit", audit, owner=session)
     registry.register_collector("timeseries", timeseries, owner=session)
     registry.register_collector("slo", slo, owner=session)

@@ -21,6 +21,7 @@ from repro.kernels.block_agg import block_agg, block_agg_batched
 from repro.kernels.filtered_agg import filtered_agg, filtered_agg_batched
 
 SF10_BLOCKS = 58_594   # ceil(60M lineitem rows / 1024)
+SF20_BLOCKS = 117_188  # ceil(120M lineitem rows / 1024)
 BLOCK_ROWS = 1024      # one (8, 128) f32 tile per column per block
 N_SAMPLED = 4096       # a 7% block sample, bucketed to a power of two
 BATCH = 4
@@ -96,38 +97,69 @@ def test_id_table_past_smem_splits_and_compiles(one_chip):
         *_q6_args(one_chip, _ids(one_chip, 8, 1 << 16), (8, 5)))
 
 
-def test_exact_scan_compiles_for_v5e_without_row_temporaries(one_chip):
-    """The exact Q6 scan of the XLA route sums each 1024-row block straight
-    from the columns: compiled for a v5e, it holds no temporary of row
-    length (one float32 channel over SF10 is 240 MB; the block partials and
-    their pairwise sum are a few MB)."""
+def _exact_q6(one_chip, blocks, kernel_mode, valid_dtype):
+    """The exact Q6 plan compiled by the physical layer over a described
+    lineitem of ``blocks`` 1024-row blocks: (compiled query, lowered)."""
     import types
 
     from repro.engine import logical as L
     from repro.engine.expr import And, Col
     from repro.engine.physical import PhysicalCompiler, ScanRuntime
 
-    rows = SF10_BLOCKS * BLOCK_ROWS
+    rows = blocks * BLOCK_ROWS
+
+    def col(dtype):
+        return jax.ShapeDtypeStruct((rows,), dtype, sharding=one_chip)
+
     dtypes = {"l_shipdate": jnp.int32, "l_discount": jnp.float32,
               "l_quantity": jnp.float32, "l_extendedprice": jnp.float32}
     table = types.SimpleNamespace(
-        block_rows=BLOCK_ROWS, padded_rows=rows, num_blocks=SF10_BLOCKS,
-        num_origin_blocks=SF10_BLOCKS,
-        columns={c: _col(one_chip, d) for c, d in dtypes.items()})
+        block_rows=BLOCK_ROWS, padded_rows=rows, num_blocks=blocks,
+        num_origin_blocks=blocks,
+        columns={c: col(d) for c, d in dtypes.items()})
     pred = And(Col("l_shipdate").between(100, 464),
                And(Col("l_discount").between(0.05, 0.07),
                    Col("l_quantity") < 24))
     plan = L.Aggregate(L.Filter(L.Scan("lineitem"), pred), (
         L.AggSpec("sum", Col("l_extendedprice") * Col("l_discount"), "rev"),))
-    compiled = PhysicalCompiler({"lineitem": table}, kernel_mode="xla") \
+    compiled = PhysicalCompiler({"lineitem": table}, kernel_mode=kernel_mode) \
         .compile_query(plan, {"lineitem": ScanRuntime("none")})
     rt = {"cols": {"lineitem": dict(table.columns)},
-          "valid": {"lineitem": _col(one_chip, jnp.bool_)},
-          "bid": {"lineitem": _col(one_chip, jnp.int32)},
+          "valid": {"lineitem": col(valid_dtype)},
+          "bid": {"lineitem": col(jnp.int32)},
           "ids": {}, "nreal": {}, "mask": {},
           "params": jax.ShapeDtypeStruct((5,), jnp.float32,
                                          sharding=one_chip)}
-    lowered = compiled.fn.lower(rt)
+    return compiled, compiled.fn.lower(rt)
+
+
+def test_exact_scan_kernel_compiles_for_v5e_at_sf20(one_chip, monkeypatch):
+    """The exact Q6 plan with the kernels on, at SF20: the scan is the
+    exact-scan kernel (``tpu_custom_call``) inside ``jit_run_exact``, and it
+    reads each column in its own dtype, ``valid`` as the table's int8 copy
+    of the bool column: no temporary of row length (a float32 cast of one
+    column would be 480 MB)."""
+    from repro.kernels.exact_agg import ops as exact_ops
+
+    # the kernel's own choice of interpret mode asks the backend, which is
+    # the CPU here: compile the chip's kernel
+    monkeypatch.setattr(exact_ops, "_auto_interpret", lambda _: False)
+    compiled, lowered = _exact_q6(one_chip, SF20_BLOCKS, "pallas", jnp.int8)
+    assert compiled.route == "pallas_exact"
+    assert "@jit_run_exact" in lowered.as_text()
+    program = lowered.compile()
+    assert "tpu_custom_call" in program.as_text()
+    temp = program.memory_analysis().temp_size_in_bytes
+    assert temp < 16 << 20, temp
+
+
+def test_exact_scan_compiles_for_v5e_without_row_temporaries(one_chip):
+    """The exact Q6 scan of the XLA route sums each 1024-row block straight
+    from the columns: compiled for a v5e, it holds no temporary of row
+    length (one float32 channel over SF10 is 240 MB; the block partials and
+    their pairwise sum are a few MB)."""
+    compiled, lowered = _exact_q6(one_chip, SF10_BLOCKS, "xla", jnp.bool_)
+    assert compiled.route == "xla_gather"
     assert "@jit_run_exact" in lowered.as_text()
     temp = lowered.compile().memory_analysis().temp_size_in_bytes
     assert temp < 16 << 20, temp

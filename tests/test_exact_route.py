@@ -6,8 +6,11 @@ sums each block's rows in float32 and adds the per-block partials as a
 balanced tree, so its answers sit within a few float32 roundings of the
 plain reference: per-block float32 sums added in float64 (numpy, below).
 The exact scan also has a jit name of its own, and TAQA's exact answers
-carry an ``exact`` span and a counter by reason.
+carry an ``exact`` span and counters by reason and by route.  With the
+kernels on, an exact scan of Q6's shape takes the ``pallas_exact`` route
+(the exact-scan kernel); other exact shapes keep the XLA route.
 """
+
 
 import numpy as np
 import pytest
@@ -136,7 +139,9 @@ def test_exact_span_carries_reason_blocks_and_bytes():
     assert sp.attrs["n_blocks"] == cat["lineitem"].num_blocks
     assert sp.attrs["bytes"] == ans.report.final_scanned_bytes > 0
     assert [c.name for c in sp.children] == ["scan"]
+    assert sp.attrs["route"] == "xla_gather"
     assert db.exact_answer_counts() == {"requested exact": 1}
+    assert db.exact_route_counts() == {"xla_gather": 1}
 
 
 def test_exact_answers_counted_by_reason_in_the_collector():
@@ -158,6 +163,107 @@ def test_exact_answers_counted_by_reason_in_the_collector():
         "no large table to sample": 3}
     assert "exact_answers_no_large_table_to_sample 3" in s.metrics.to_text()
     s.close()
+
+
+# -- the pallas_exact route ----------------------------------------------------
+
+def _route_catalog():
+    """16 blocks of 1,024 rows: a multiple of 128 rows, as the exact-scan
+    kernel needs."""
+    return tpch_catalog(16_384, 1024, seed=3)
+
+
+def _grouped_plan():
+    return _plan("l_returnflag", GROUPS)
+
+
+def _join_plan():
+    return L.Aggregate(
+        child=L.Filter(L.Join(L.Scan("lineitem"), L.Scan("orders"),
+                              "l_orderkey", "o_orderkey"),
+                       Col("o_orderdate") < 900),
+        aggs=(L.AggSpec("sum", Col("l_extendedprice"), "rev"),))
+
+
+def _sampled(plan):
+    return L.rewrite_scans(
+        plan, {"lineitem": L.SampleClause("block", 0.5, seed=4)})
+
+
+@pytest.mark.parametrize("name,plan,route", [
+    ("exact_q6", _plan(), "pallas_exact"),
+    ("exact_grouped", _grouped_plan(), "xla_gather"),
+    ("exact_join", _join_plan(), "xla_gather"),
+    ("sampled_q6", _sampled(_plan()), "pallas_filtered"),
+])
+def test_pallas_mode_routes_by_plan_shape(name, plan, route):
+    """With the kernels on, an exact Q6 scan takes the exact-scan kernel;
+    grouped and join exact scans keep the XLA route; a sampled final keeps
+    the sampled-block kernel.  The exact scan keeps its jit name."""
+    ex = Executor(_route_catalog(), kernel_mode="pallas")
+    res = ex.execute(plan)
+    assert res.route == route
+    (compiled,) = ex.physical.executables()
+    assert compiled.route == route
+    exact = name.startswith("exact")
+    assert compiled.fn.__name__ == ("run_exact" if exact else "run")
+
+
+@pytest.mark.parametrize("kernel_mode,route", [
+    ("xla", "xla_gather"), ("pallas", "pallas_exact")])
+def test_exact_span_and_collector_name_the_route(kernel_mode, route):
+    cat = _route_catalog()
+    s = Session(cat, seed=5, config=SessionConfig(
+        async_workers=0, result_cache_size=0, tracing=True,
+        kernel_mode=kernel_mode))
+    assert s.metrics.tree()["exact_routes"] == {}
+    sql = ("SELECT SUM(l_extendedprice * l_discount) AS rev FROM lineitem "
+           "WHERE l_shipdate BETWEEN 100 AND {} AND l_quantity < 24 "
+           "ERROR 5% CONFIDENCE 95%")
+    handles = [s.submit(sql.format(d)) for d in (900, 1200)]
+    s.drain()
+    for h in handles:
+        assert h.result().report.fallback == "no large table to sample"
+        (sp,) = [x for x in _walk(h.trace()["root"]) if x["name"] == "exact"]
+        assert sp["attrs"]["route"] == route
+    assert s.db.exact_route_counts() == {route: 2}
+    assert s.metrics.tree()["exact_routes"] == {route: 2}
+    assert f"exact_routes_{route} 2" in s.metrics.to_text()
+    # the reasons are counted as before, beside the routes
+    assert s.metrics.tree()["exact_answers"] == {
+        "no large table to sample": 2}
+    s.close()
+
+
+def test_valid_bytes_made_once_when_workers_meet_it_together():
+    """The pool's workers reach a table's first exact scan together: every
+    one gets the same int8 copy of ``valid``, made once."""
+    import sys
+    import threading
+
+    tab = _route_catalog()["lineitem"]
+    n = 16
+    start = threading.Barrier(n)
+    got = [None] * n
+
+    def work(i):
+        start.wait(timeout=30)
+        got[i] = tab.valid_bytes()
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(i,)) for i in range(n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert len({id(g) for g in got}) == 1
+    np.testing.assert_array_equal(np.asarray(got[0]),
+                                  np.asarray(tab.valid).astype(np.int8))
 
 
 def _walk(span):

@@ -6,6 +6,7 @@ import pytest
 
 from repro.kernels.block_agg import block_agg, block_agg_batched
 from repro.kernels.block_agg import ops as block_agg_ops
+from repro.kernels.exact_agg import exact_agg, exact_agg_ref
 from repro.kernels.filtered_agg import filtered_agg, filtered_agg_batched
 from repro.kernels.flash_attn import flash_attention
 from repro.kernels.gla_chunk import gla_chunked
@@ -131,6 +132,169 @@ def test_id_table_split_across_launches_is_bitwise_one_launch(
     monkeypatch.setattr(block_agg_ops, "MAX_PREFETCH_IDS", 4)
     split = np.asarray(run())
     np.testing.assert_array_equal(split, whole)
+
+
+# -- exact_agg -------------------------------------------------------------------
+
+XBR = 1024                    # rows a block
+XROWS = 37 * XBR - 300        # 37 blocks, the last one padded
+XSTEP = 64                    # rows of 128 a grid step: 8 blocks, 5 steps
+
+
+def _xdata(seed=17):
+    rng = np.random.default_rng(seed)
+    qty = rng.integers(1, 51, XROWS).astype(np.float32)
+    return {
+        "l_quantity": qty,
+        "l_extendedprice": (qty * rng.uniform(900, 1100, XROWS)).astype(
+            np.float32),
+        "l_discount": rng.integers(0, 11, XROWS).astype(np.float32)
+        / np.float32(100),
+        "l_shipdate": np.sort(rng.integers(0, 2526, XROWS)).astype(np.int32),
+        # order keys past 2^24, where float32 rounds neighbouring ints together
+        "l_orderkey": (2 ** 24 + rng.integers(-40, 40, XROWS)).astype(np.int32),
+    }
+
+
+def _q6_channels(cols, ok, p):
+    ext, disc, qty, ship = cols
+    keep = (ok & (ship >= p[0]) & (ship <= p[1]) & (disc >= p[2])
+            & (disc <= p[3]) & (qty < p[4]))
+    return [jnp.where(keep, ext * disc, 0.0),
+            jnp.where(keep, jnp.ones(keep.shape, jnp.float32), 0.0)]
+
+
+def _live_channels(cols, ok, p):
+    # no predicate: every live row counts, so only the kernel's own mask
+    # keeps rows past the table's end out
+    return [jnp.where(ok, cols[0], 0.0),
+            jnp.where(ok, jnp.ones(ok.shape, jnp.float32), 0.0)]
+
+
+@pytest.mark.parametrize("channels", [_q6_channels, _live_channels],
+                         ids=["q6", "unfiltered"])
+@pytest.mark.parametrize("step_rows", [32, XSTEP, 2048],
+                         ids=["chunk", "ragged", "one_step"])
+def test_exact_agg_matches_ref(monkeypatch, step_rows, channels):
+    """Per-step sums against the per-block oracle: a ragged last step (37
+    blocks, 8 a step), a step of one chunk, and one step past the table's
+    end.  Off the chip the interpreter fills the rows past the end with NaN
+    and a live ``valid``: the kernel must mask them out."""
+    from repro.kernels.exact_agg import kernel as exact_kernel
+
+    monkeypatch.setattr(exact_kernel, "STEP_ROWS", step_rows)
+    d = _xdata()
+    pad = -XROWS % XBR
+    cols = [jnp.asarray(np.pad(d[c], (0, pad))) for c in
+            ("l_extendedprice", "l_discount", "l_quantity", "l_shipdate")]
+    valid = jnp.asarray(np.arange(XROWS + pad) < XROWS, jnp.int8)
+    params = jnp.asarray([100, 1500, 0.05, 0.07, 24], jnp.float32)
+    got = np.asarray(exact_agg(cols, valid, params, channels, 2))
+    assert got.shape == (-(-(XROWS + pad) // (step_rows * 128)), 2)
+    want = np.asarray(exact_agg_ref(cols, valid, params, channels,
+                                    block_rows=XBR))
+    np.testing.assert_allclose(got.sum(0, dtype=np.float64),
+                               want.sum(0, dtype=np.float64), rtol=1e-6)
+    assert got[:, 1].sum() == want[:, 1].sum() > 0
+
+
+@pytest.fixture(scope="module")
+def xcatalog():
+    from repro.engine.table import BlockTable
+    return {"lineitem": BlockTable.from_numpy("lineitem", _xdata(), XBR)}
+
+
+def _xcases():
+    from repro.engine.expr import And, Col
+    ext, disc = Col("l_extendedprice"), Col("l_discount")
+    q6 = And(Col("l_shipdate").between(300, 1200),
+             And(disc.between(0.05, 0.07), Col("l_quantity") < 24))
+    rev = ("sum", ext * disc)
+    # l_shipdate is sorted: rows 0 and XROWS - 1 hold its smallest and
+    # largest day, so BETWEEN those days keeps both ends of the table
+    d = _xdata()
+    first, last = int(d["l_shipdate"][0]), int(d["l_shipdate"][-1])
+    return {
+        "q6": (q6, [rev, ("count", None)], None),
+        "bounds_hit_at_both_ends": (
+            And(Col("l_shipdate").between(first, last),
+                Col("l_quantity") < 25), [rev], None),
+        "count_only": (q6, [("count", None)], None),
+        "sum_x": (q6, [("sum", ext)], None),
+        "cleared_valid": (q6, [rev], 3),
+        "int32_past_2_24": (
+            And(Col("l_orderkey").between(2 ** 24 - 3, 2 ** 24 + 1),
+                Col("l_quantity") < 30), [rev, ("count", None)], None),
+        # one strict upper bound on an int32 column: off the chip the rows
+        # past the table's end hold int32's least value and pass it
+        "int32_upper_bound_only": (
+            Col("l_orderkey") < 2 ** 24 + 1, [("sum", ext), ("count", None)],
+            None),
+    }
+
+
+def _xref(d, valid, pred_name):
+    """Rows kept, as the routes keep them: every column compared with its
+    bound in float32, int32 columns cast first."""
+    f = {c: v.astype(np.float32) for c, v in d.items()}
+    first, last = f["l_shipdate"][0], f["l_shipdate"][-1]
+    if pred_name == "int32_upper_bound_only":
+        keep = f["l_orderkey"] < np.float32(2 ** 24 + 1)
+    elif pred_name == "int32_past_2_24":
+        keep = ((f["l_orderkey"] >= np.float32(2 ** 24 - 3))
+                & (f["l_orderkey"] <= np.float32(2 ** 24 + 1))
+                & (f["l_quantity"] < 30))
+    elif pred_name == "bounds_hit_at_both_ends":
+        keep = ((f["l_shipdate"] >= first) & (f["l_shipdate"] <= last)
+                & (f["l_quantity"] < 25))
+    else:
+        keep = ((f["l_shipdate"] >= 300) & (f["l_shipdate"] <= 1200)
+                & (f["l_discount"] >= np.float32(0.05))
+                & (f["l_discount"] <= np.float32(0.07))
+                & (f["l_quantity"] < 24))
+    return keep & valid[:XROWS]
+
+
+@pytest.mark.parametrize("case", list(_xcases()))
+def test_exact_agg_plan_matches_blockwise_totals_and_float64(
+        xcatalog, monkeypatch, case):
+    """The exact plan on the ``pallas_exact`` route (interpret mode) against
+    the ``xla_gather`` route's blockwise totals and a float64 numpy sum,
+    over 37 blocks whose last is padded, 8 blocks a grid step."""
+    from repro.engine import logical as L
+    from repro.engine.executor import Executor
+    from repro.kernels.exact_agg import kernel as exact_kernel
+
+    monkeypatch.setattr(exact_kernel, "STEP_ROWS", XSTEP)
+    pred, aggs, clear_every = _xcases()[case]
+    cat = dict(xcatalog)
+    tab = cat["lineitem"]
+    valid = np.asarray(tab.valid).copy()
+    if clear_every:
+        valid[::clear_every] = False
+        cat["lineitem"] = tab.with_valid(jnp.asarray(valid))
+    plan = L.Aggregate(L.Filter(L.Scan("lineitem"), pred), tuple(
+        L.AggSpec(op, e, f"a{i}") for i, (op, e) in enumerate(aggs)))
+    got = Executor(cat, kernel_mode="pallas").execute(plan)
+    assert got.route == "pallas_exact"
+    xla = Executor(cat, kernel_mode="xla").execute(plan)
+    assert xla.route == "xla_gather"
+    np.testing.assert_array_equal(got.group_counts, xla.group_counts)
+    np.testing.assert_allclose(got.values, xla.values, rtol=1e-6)
+
+    d = _xdata()
+    keep = _xref(d, valid, case)
+    assert 0 < keep.sum() < XROWS or case == "bounds_hit_at_both_ends"
+    assert got.group_counts[0] == keep.sum()
+    for i, (op, e) in enumerate(aggs):
+        if op == "count":
+            want = keep.sum()
+        else:
+            v = d["l_extendedprice"].astype(np.float64)
+            if e is not None and "l_discount" in e.columns():
+                v = v * d["l_discount"]
+            want = v[keep].sum()
+        np.testing.assert_allclose(got.values[i, 0], want, rtol=1e-5)
 
 
 # -- flash attention -------------------------------------------------------------
